@@ -98,5 +98,7 @@ def manual_mode():
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     asyncio.run(serve_mode())
     manual_mode()

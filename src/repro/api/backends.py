@@ -119,9 +119,15 @@ class BackendProgram:
     batch axis ``(B, *shape)``; ``aux`` may be ``None``, one shared grid of
     ``shape``, or a batch of ``(B, *shape)``.  Backends that do not provide
     it (``execute_batch=None``) still serve ``StencilPlan.run_batch`` via a
-    per-element fallback loop."""
+    per-element fallback loop.
+
+    ``lower(grid, coeffs, iters, aux, batch)`` (optional) lowers the
+    executable ``execute`` (or, with ``batch``, ``execute_batch``) would
+    dispatch to, for arrays or ``jax.ShapeDtypeStruct`` specs — the
+    ``jax.stages.Lowered`` whose ``compile()`` shows the chip's HLO."""
     execute: ExecuteFn
     execute_batch: Optional[ExecuteFn] = None
+    lower: Optional[Callable] = None
 
 
 def as_program(obj: Union[ExecuteFn, BackendProgram]) -> BackendProgram:
@@ -160,7 +166,8 @@ def _instrument(program: BackendProgram) -> BackendProgram:
                                  {"batch": grids.shape[0]})
         execute_batch._fault_instrumented = True
 
-    return BackendProgram(execute=execute, execute_batch=execute_batch)
+    return BackendProgram(execute=execute, execute_batch=execute_batch,
+                          lower=program.lower)
 
 
 _REGISTRY: Dict[str, Backend] = {}
@@ -387,22 +394,25 @@ def _engine_backend(problem, config, geom):
     return _vmapped_program("engine", problem, config, geom, body)
 
 
+def check_pallas_dtype(problem: StencilProblem) -> None:
+    """Plan-time validation: fail before any execute (or any geometry is
+    sized for the dtype's tiles), and say what IS supported."""
+    if problem.dtype not in PALLAS_SUPPORTED_DTYPES:
+        raise ValueError(
+            f"the Pallas kernels support dtypes "
+            f"{list(PALLAS_SUPPORTED_DTYPES)}; "
+            f"got problem.dtype={problem.dtype!r} — use the 'engine' or "
+            f"'reference' backend for other dtypes")
+
+
 def _make_pallas_backend(force_interpret: bool):
     def factory(problem, config, geom):
         from repro.kernels.ops import (fused_chain_loop, fused_dag_loop,
                                        fused_superstep_loop, pack_coeffs,
                                        pack_dag_coeffs, pack_program_coeffs,
                                        _pad_blocked)
-        # plan-time validation (satellite bugfix): fail before any execute,
-        # and say what IS supported
-        if problem.dtype not in PALLAS_SUPPORTED_DTYPES:
-            raise ValueError(
-                f"the Pallas kernels support dtypes "
-                f"{list(PALLAS_SUPPORTED_DTYPES)}; "
-                f"got problem.dtype={problem.dtype!r} — use the 'engine' or "
-                f"'reference' backend for other dtypes")
         bc = problem.structural_bc   # sizes padding + the stream extension
-        interpret = force_interpret or config.interpret
+        interpret = force_interpret
         tag = "pallas_interpret" if interpret else "pallas"
         get = _program_cache(config.exec_cache)
         donate = _donate_ok(config)
@@ -487,7 +497,29 @@ def _make_pallas_backend(force_interpret: bool):
             return fn(gps, pack(coeffs),
                       jnp.asarray(iters, jnp.int32), aux_p)
 
-        return BackendProgram(execute, execute_batch)
+        def lower(grid, coeffs, iters, aux=None, batch=False):
+            # shapes only, all placed where ``grid`` is (a described chip's
+            # sharding lowers for that chip without one attached)
+            where = getattr(grid, "sharding", None)
+
+            def spec(x, f=None):
+                out = jax.eval_shape(f, x) if f else x
+                return jax.ShapeDtypeStruct(out.shape, out.dtype,
+                                            sharding=where)
+            pad = lambda x: _pad_blocked(x, geom, bc)  # noqa: E731
+            if batch:
+                mode = _aux_mode(problem, aux)
+                fn = get(_exec_key(tag, problem, geom, batch=grid.shape[0],
+                                   aux_mode=mode, extra=extra),
+                         lambda: build_batch(mode))
+            else:
+                fn = single
+            return fn.lower(spec(grid, pad), spec(pack(coeffs)),
+                            jax.ShapeDtypeStruct((), jnp.int32,
+                                                 sharding=where),
+                            None if aux is None else spec(aux, pad))
+
+        return BackendProgram(execute, execute_batch, lower)
     return factory
 
 
@@ -534,24 +566,34 @@ def _distributed_backend(problem, config, geom):
                     if problem.n_stages > 1 and not problem.is_dag else None),
             dag=problem.exec_dag if problem.is_dag else None)
 
-    def execute(grid, coeffs, iters, aux=None):
+    def program(batch, aux):
         # built lazily on first call (not at plan time): plan() must stay
         # executable-free for the distributed backend so schedulers can plan
         # against a mesh description without touching real devices
-        single = get(_exec_key("distributed", problem, geom, extra=base_key),
-                     lambda: build(False, False))
+        if not batch:
+            return get(_exec_key("distributed", problem, geom,
+                                 extra=base_key),
+                       lambda: build(False, False))
+        mode = _aux_mode(problem, aux)
+        return get(_exec_key("distributed", problem, geom, batch=batch,
+                             aux_mode=mode, extra=base_key),
+                   lambda: build(True, mode == "batched"))
+
+    def args(grid, coeffs, iters, aux):
         aux_in = aux if aux is not None else jnp.zeros((), jnp.float32)
-        return single(grid, aux_in, coeffs, jnp.asarray(iters, jnp.int32))
+        return grid, aux_in, coeffs, jnp.asarray(iters, jnp.int32)
+
+    def execute(grid, coeffs, iters, aux=None):
+        return program(None, aux)(*args(grid, coeffs, iters, aux))
 
     def execute_batch(grids, coeffs, iters, aux=None):
-        mode = _aux_mode(problem, aux)
-        key = _exec_key("distributed", problem, geom, batch=grids.shape[0],
-                        aux_mode=mode, extra=base_key)
-        fn = get(key, lambda: build(True, mode == "batched"))
-        aux_in = aux if aux is not None else jnp.zeros((), jnp.float32)
-        return fn(grids, aux_in, coeffs, jnp.asarray(iters, jnp.int32))
+        return program(grids.shape[0], aux)(*args(grids, coeffs, iters, aux))
 
-    return BackendProgram(execute, execute_batch)
+    def lower(grid, coeffs, iters, aux=None, batch=False):
+        fn = program(grid.shape[0] if batch else None, aux)
+        return fn.lower(*args(grid, coeffs, iters, aux))
+
+    return BackendProgram(execute, execute_batch, lower)
 
 
 register_backend("reference", _reference_backend)

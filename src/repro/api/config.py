@@ -12,7 +12,7 @@ import dataclasses
 from typing import Optional, Tuple, Union
 
 from repro.core import precision
-from repro.core.perf_model import DEVICES, Device
+from repro.core.perf_model import DEVICES, Device, attached_device
 
 #: Accepted ``RunConfig.autotune`` modes (``False`` disables; the legacy
 #: booleans are aliases: ``True`` -> ``"model"``).
@@ -46,7 +46,9 @@ class RunConfig:
     #: ``perf_model.PAR_VEC_CANDIDATES``) when autotuning, else defaults to 1.
     par_vec: Optional[int] = None
     autotune: Union[bool, str] = False
-    device: Union[Device, str] = "tpu_v5e"
+    #: the chip the model prices against: ``None`` resolves the attached
+    #: chip's ``device_kind`` (:func:`~repro.core.perf_model.attached_device`)
+    device: Union[Device, str, None] = None
     #: storage bytes per cell used for traffic/VMEM pricing. ``None`` (the
     #: default) derives it from the problem's storage dtype via
     #: :func:`repro.core.precision.cell_bytes` (4 for f32, 2 for bf16); an
@@ -56,7 +58,6 @@ class RunConfig:
     iters_hint: int = 100        # iteration count used for ranking/prediction
     mesh: Optional[object] = None          # jax.sharding.Mesh (distributed)
     axis_map: Optional[Tuple] = None       # grid axis -> mesh axis names
-    interpret: bool = False      # force Pallas interpret mode
     # --- throughput knobs (serving path) ------------------------------------
     #: let backends donate the *internal* padded super-step carry to XLA
     #: (donate_argnums on the padded grid — never on a caller-visible array,
@@ -120,6 +121,8 @@ class RunConfig:
     def resolved_device(self) -> Device:
         if isinstance(self.device, Device):
             return self.device
+        if self.device is None:
+            return attached_device()
         if self.device not in DEVICES:
             raise ValueError(f"unknown device {self.device!r}; "
                              f"have: {sorted(DEVICES)}")

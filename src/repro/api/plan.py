@@ -17,13 +17,13 @@ import jax
 import jax.numpy as jnp
 
 from repro.api import schedule_cache, tuner
-from repro.api.backends import (ExecuteFn, as_program, get_backend,
-                                resolve_axis_map)
+from repro.api.backends import (ExecuteFn, as_program, check_pallas_dtype,
+                                get_backend, resolve_axis_map)
 from repro.api.config import RunConfig
 from repro.api.problem import StencilProblem
 from repro.core import perf_model
 from repro.core.blocking import (BlockGeometry, extended_geometry,
-                                 superstep_traffic_bytes)
+                                 superstep_traffic_bytes, tpu_tiles)
 from repro.core.perf_model import Device, Prediction
 
 
@@ -52,6 +52,18 @@ PAR_VEC_BACKENDS = ("pallas", "pallas_interpret")
 #: are unrestricted — they may well wrap the vectorized kernels.
 SCALAR_TICK_BACKENDS = ("engine", "reference", "distributed")
 
+#: backends compiled by Mosaic for the chip: their geometry is tile-aligned
+#: (``BlockGeometry.align``) so every HBM DMA window starts on a tile
+ALIGNED_BACKENDS = ("pallas",)
+
+
+def _tiles(problem: StencilProblem, config: RunConfig):
+    """``(stream_tile, align)`` the backend's kernels need (``(1, ())`` for
+    backends that run any geometry)."""
+    if config.backend not in ALIGNED_BACKENDS:
+        return 1, ()
+    return tpu_tiles(problem.ndim, config.resolved_cell_bytes(problem.dtype))
+
 
 def _candidate_shortlist(problem: StencilProblem, config: RunConfig,
                          device: Device, n_chips: int, chip_grid,
@@ -75,7 +87,8 @@ def _candidate_shortlist(problem: StencilProblem, config: RunConfig,
         par_time=config.par_time,
         bsize=config.normalized_bsize(problem.ndim),
         par_vec=par_vec, top_k=top_k,
-        bc=problem.structural_bc)
+        bc=problem.structural_bc,
+        aligned=config.backend in ALIGNED_BACKENDS)
     if not cands:
         raise ValueError(
             f"no VMEM-feasible (bsize, par_time, par_vec) for "
@@ -95,7 +108,8 @@ def _resolve_schedule(problem: StencilProblem, config: RunConfig,
     par_time = config.par_time
     bsize = config.normalized_bsize(problem.ndim)
     if not config.autotune and par_time is not None and bsize is not None:
-        return par_time, bsize, config.par_vec or 1, ()
+        par_vec = config.par_vec or _tiles(problem, config)[0]
+        return par_time, bsize, par_vec, ()
     cands = _candidate_shortlist(problem, config, device, n_chips, chip_grid)
     best = cands[0].geom
     return best.par_time, best.bsize, best.par_vec, tuple(cands)
@@ -134,7 +148,8 @@ def _resolve_measured(problem: StencilProblem, config: RunConfig,
                     par_time, device,
                     config.resolved_cell_bytes(problem.dtype),
                     n_chips, chip_grid,
-                    bc=problem.structural_bc, par_vec=par_vec)
+                    bc=problem.structural_bc, par_vec=par_vec,
+                    aligned=config.backend in ALIGNED_BACKENDS)
             except (KeyError, TypeError, ValueError):
                 entry = None
             else:
@@ -175,6 +190,8 @@ def plan(problem: StencilProblem, config: Optional[RunConfig] = None,
     if config is None:
         config = RunConfig()
     factory = get_backend(config.backend)       # fail fast on unknown names
+    if config.backend in PAR_VEC_BACKENDS:
+        check_pallas_dtype(problem)
     _validate_distributed(problem, config)
     device = config.resolved_device()
     n_chips, chip_grid = _chip_layout(problem, config)
@@ -198,9 +215,15 @@ def plan(problem: StencilProblem, config: Optional[RunConfig] = None,
         else:
             par_time, bsize, par_vec, cands = _resolve_schedule(
                 problem, config, device, n_chips, chip_grid)
+        stream_tile, align = _tiles(problem, config)
+        if par_vec % stream_tile:
+            raise ValueError(
+                f"par_vec={par_vec} is not a multiple of {stream_tile}: the "
+                f"{config.backend!r} kernels move (par_vec, ...) slabs that "
+                f"must fill whole {problem.dtype} tiles")
         geom = BlockGeometry(problem.ndim, problem.shape,
                              problem.stencil.radius, par_time, tuple(bsize),
-                             par_vec)
+                             par_vec, align)
     except ValueError:
         if config.backend != "reference":
             raise
@@ -210,7 +233,7 @@ def plan(problem: StencilProblem, config: Optional[RunConfig] = None,
                        n_chips=n_chips, chip_grid=chip_grid,
                        candidates=cands, _execute=program.execute,
                        _execute_batch=program.execute_batch,
-                       tuned_from_cache=from_cache)
+                       _lower=program.lower, tuned_from_cache=from_cache)
 
 
 @dataclasses.dataclass
@@ -234,6 +257,8 @@ class StencilPlan:
     #: then falls back to a per-element loop)
     _execute_batch: Optional[ExecuteFn] = dataclasses.field(
         default=None, repr=False)
+    #: lowering entry point (None for backends without one)
+    _lower: Optional[object] = dataclasses.field(default=None, repr=False)
     #: True when the measured schedule was served by the persistent cache
     #: (no candidate was re-timed for this plan)
     tuned_from_cache: bool = False
@@ -411,6 +436,20 @@ class StencilPlan:
         return timings
 
     # --- introspection ------------------------------------------------------
+    def lower(self, grid, iters: int = 1, coeffs=None, *, aux=None,
+              batch: bool = False):
+        """The ``jax.stages.Lowered`` executable :meth:`run` (or, with
+        ``batch``, :meth:`run_batch`) dispatches to for ``grid``, which
+        may be an array or a ``jax.ShapeDtypeStruct``.  Its ``compile()``
+        is what the chip's compiler makes of the plan; give the specs a
+        described TPU's sharding to compile for a chip that is not
+        attached."""
+        if self._lower is None:
+            raise ValueError(f"backend {self.backend!r} has no lowering "
+                             "entry point")
+        return self._lower(grid, self._coeff_payload(coeffs), iters, aux,
+                           batch)
+
     def predicted(self, iters: Optional[int] = None,
                   device: Optional[Device] = None,
                   batch: int = 1) -> Prediction:
@@ -426,7 +465,8 @@ class StencilPlan:
             geom.bsize, geom.par_time, device or self.device,
             self.config.resolved_cell_bytes(self.problem.dtype),
             self.n_chips, self.chip_grid,
-            batch=batch, bc=self.problem.structural_bc, par_vec=geom.par_vec)
+            batch=batch, bc=self.problem.structural_bc, par_vec=geom.par_vec,
+            aligned=bool(geom.align))
 
     def traffic_report(self, iters: Optional[int] = None) -> dict:
         """Model traffic (paper Eq. 7/8) vs. the Pallas kernels' exact DMA

@@ -8,7 +8,7 @@ persisted to a small JSON file keyed by everything that determines the
 optimum:
 
     (stencil, shape, dtype, boundary condition, cell_bytes, backend,
-     interpret flag, execution platform, device, n_chips / chip_grid,
+     execution platform, device, n_chips / chip_grid,
      pinned par_time/bsize, code-version salt)
 
 The *code-version salt* is a content hash of the stencil/kernel/engine/
@@ -148,10 +148,9 @@ def schedule_key(problem, config, device, n_chips: int, chip_grid,
         # served to a periodic plan
         f"bc={problem.bc.token()}",
         f"cb={config.resolved_cell_bytes(problem.dtype)}",
+        # interpret-mode timings (backend=pallas_interpret) have no relation
+        # to compiled ordering: the backend keeps them apart
         f"backend={config.backend}",
-        # interpret-mode timings have no relation to compiled ordering:
-        # never let one serve the other from the cache
-        f"interp={int(bool(config.interpret))}",
         # config.device is only the perf-model's label; the stopwatch ran on
         # the actual jax platform — a shared cache file must not let a
         # CPU-timed winner serve a TPU process (or vice versa)

@@ -14,12 +14,16 @@ compute-block stride ``csize = bsize - 2*size_halo`` (Eq. 4); the number of
 blocks per dimension is ``ceil(dim / csize)`` (Eq. 5), and out-of-bound
 compute in the last block is discarded at write time.
 
-TPU alignment note (paper §3.3.3 analogue): the paper pads device buffers so
-external accesses stay 512-bit aligned.  On TPU the analogous constraint is
-lane alignment — we require ``csize % lane == 0`` (lane = 128 for f32) for the
-innermost blocked dimension, which makes every block's start offset and every
-compute-block write lane-aligned.  512 bits = 16 f32 on the FPGA; 128 lanes =
-512 bytes on TPU — the same trick, one power of two up.
+TPU alignment (paper §3.3.3 analogue): the paper pads device buffers so
+external accesses stay 512-bit aligned.  On TPU every HBM DMA window must
+start on a tile boundary: a multiple of 128 lanes on the minor axis and of the
+dtype's sublane count (8 for f32, 16 for bf16) on the second-minor one.  A
+geometry built with ``align`` (see :func:`tpu_tiles`) rounds the padded
+layout's leading halo up to the tile (:attr:`BlockGeometry.pad`) and keeps
+``csize`` a tile multiple, so every block's input window (``i * csize``) and
+output window (``i * csize + pad``) starts on a tile; the extra ``pad -
+size_halo`` columns are redundant halo.  Without ``align`` (interpret mode,
+the engine) ``pad == size_halo`` and the geometry is the paper's.
 
 Stream-axis vectorization (paper §3.3 ``par_vec``): each pipeline tick
 advances ``par_vec`` rows/planes instead of one, so the rolling windows hold
@@ -32,8 +36,11 @@ each sublane carries a real row.  See DESIGN.md §2.2.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import math
 from typing import Sequence, Tuple
+
+from repro.core.precision import sublanes_for
 
 LANE = 128      # lanes per VREG row on TPU (dtype-independent)
 SUBLANE = 8     # sublanes of the 4-byte (f32) minimum tile; 16-bit tiles
@@ -49,26 +56,41 @@ class BlockGeometry:
     par_time: int                  # fused time-steps per HBM round-trip
     bsize: Tuple[int, ...]         # block extent per *blocked* dim (trailing axes)
     par_vec: int = 1               # rows/planes advanced per pipeline tick (V)
+    #: per-blocked-dim tile the layout's halo and ``csize`` round up to
+    #: (``tpu_tiles``); ``()`` = no alignment
+    align: Tuple[int, ...] = ()
 
     def __post_init__(self):
         assert self.ndim == len(self.dims)
         assert len(self.bsize) == self.ndim - 1, "streaming axis is not blocked"
+        assert len(self.align) in (0, self.ndim - 1), "one tile per blocked dim"
         if self.par_vec < 1:
             raise ValueError(f"par_vec must be >= 1, got {self.par_vec}")
-        if any(b <= 2 * self.size_halo for b in self.bsize):
+        if any(b <= 2 * p for b, p in zip(self.bsize, self.pad)):
             raise ValueError(
-                f"bsize {self.bsize} too small for halo {self.size_halo} "
-                f"(need bsize > 2*rad*par_time = {2 * self.size_halo})")
+                f"bsize {self.bsize} too small for halo {self.pad} "
+                f"(need bsize > 2 * pad per dim; rad*par_time = "
+                f"{self.size_halo}, align = {self.align})")
+        if any(b % a for b, a in zip(self.bsize, self.align)):
+            raise ValueError(f"bsize {self.bsize} is not a multiple of the "
+                             f"tiles {self.align}")
 
     # --- paper Eq. (2): halo width per side, in the last PE -----------------
     @property
     def size_halo(self) -> int:
         return self.rad * self.par_time
 
+    @property
+    def pad(self) -> Tuple[int, ...]:
+        """Leading halo of the padded layout per blocked dim: ``size_halo``
+        rounded up to that dim's tile (equal to it without ``align``)."""
+        align = self.align or (1,) * (self.ndim - 1)
+        return tuple(-(-self.size_halo // a) * a for a in align)
+
     # --- paper Eq. (4): compute-block extent --------------------------------
     @property
     def csize(self) -> Tuple[int, ...]:
-        return tuple(b - 2 * self.size_halo for b in self.bsize)
+        return tuple(b - 2 * p for b, p in zip(self.bsize, self.pad))
 
     # --- paper Eq. (5): blocks per blocked dimension -------------------------
     @property
@@ -106,11 +128,11 @@ class BlockGeometry:
     def blocked_dims(self) -> Tuple[int, ...]:
         return self.dims[1:]
 
-    # --- padded extents: bnum*csize + 2*halo (what the engine/kernels see) --
+    # --- padded extents: bnum*csize + 2*pad (what the engine/kernels see) ---
     @property
     def padded_dims(self) -> Tuple[int, ...]:
-        return tuple(n * c + 2 * self.size_halo
-                     for n, c in zip(self.bnum, self.csize))
+        return tuple(n * c + 2 * p
+                     for n, c, p in zip(self.bnum, self.csize, self.pad))
 
     @property
     def num_blocks(self) -> int:
@@ -120,7 +142,7 @@ class BlockGeometry:
     @property
     def trav(self) -> Tuple[int, ...]:
         """Alias of :attr:`padded_dims`: the Eq. (7) 'traversed' extent
-        (``bnum * csize + 2*halo``) is exactly the padded extent the
+        (``bnum * csize + 2*pad``) is exactly the padded extent the
         engine/kernels see — one definition, two paper names."""
         return self.padded_dims
 
@@ -252,28 +274,77 @@ def extended_geometry(geom: BlockGeometry, bc) -> BlockGeometry:
         geom, dims=(geom.stream_dim + 2 * ext,) + geom.blocked_dims)
 
 
-def bsize_feasible(rad: int, par_time: int, bsize: Sequence[int]) -> bool:
+def tpu_tiles(ndim: int, cell_bytes: int = 4) -> Tuple[int, Tuple[int, ...]]:
+    """``(stream_tile, align)``: the tiles the compiled kernels' HBM DMA
+    windows must respect for a rank-``ndim`` grid of ``cell_bytes`` cells.
+
+    The minor axis of every HBM array is tiled by 128 lanes and the
+    second-minor one by the dtype's sublanes (:func:`sublanes_for`).  In 2D
+    the stream axis is the second-minor one, so each ``(V, bsize)`` slab DMA
+    needs ``V`` a sublane multiple; in 3D the blocked y axis is, so its
+    halo and compute extent round to sublanes; in 1D the stream is the lane
+    axis.  ``par_vec`` must be a multiple of ``stream_tile``; ``align`` is
+    :attr:`BlockGeometry.align`."""
+    sub = sublanes_for(cell_bytes)
+    if ndim == 1:
+        return LANE, ()
+    if ndim == 2:
+        return sub, (LANE,)
+    return 1, (sub, LANE)
+
+
+def bsize_feasible(rad: int, par_time: int, bsize: Sequence[int],
+                   align: Sequence[int] = ()) -> bool:
     """True iff ``bsize`` yields a valid geometry after halo widening.
 
     Small grids at high ``par_time`` otherwise produce candidates that
     :class:`BlockGeometry` rejects: the compute block ``csize = bsize -
-    2*rad*par_time`` collapses to <= 0.  (No grid-extent check is needed: a
-    block can never exceed the padded extent, since ``padded = bnum*csize +
-    2*halo >= csize + 2*halo = bsize`` whenever csize > 0.)"""
+    2*pad`` collapses to <= 0, or (aligned) ``bsize`` is off the tile.  (No
+    grid-extent check is needed: a block can never exceed the padded extent,
+    since ``padded = bnum*csize + 2*pad >= bsize`` whenever csize > 0.)"""
     halo = rad * par_time
-    return all(b > 2 * halo for b in bsize)
+    align = tuple(align) or (1,) * len(bsize)
+    return all(b > 2 * (-(-halo // a) * a) and b % a == 0
+               for b, a in zip(bsize, align))
+
+
+def _aligned_extents(dim: int, tile: int, cap: int) -> list:
+    """Compute extents for one aligned blocked dim: power-of-two tile
+    multiples below the grid extent, then the extent rounded up to the
+    tile (one block spanning the dim), all at most ``cap``."""
+    full = -(-dim // tile) * tile
+    out, c = [], tile
+    while c < full and c <= cap:
+        out.append(c)
+        c *= 2
+    if full <= cap:
+        out.append(full)
+    return out or [tile]
 
 
 def choose_bsize_candidates(ndim: int, dims: Sequence[int], rad: int = 1,
-                            par_time: int | None = None) -> list:
+                            par_time: int | None = None,
+                            align: Sequence[int] = ()) -> list:
     """Power-of-two block extents, lane-aligned (paper §5.3 restrictions).
 
     When ``par_time`` is given, candidates infeasible for that temporal
     depth (see :func:`bsize_feasible`) are dropped; the result may be empty
-    — callers autotuning a small grid must handle that, not crash."""
+    — callers autotuning a small grid must handle that, not crash.
+
+    With ``align`` (the compiled kernels' tiles, :func:`tpu_tiles`) the
+    sweep is over tile-multiple *compute* extents instead, each block
+    ``csize + 2*pad`` wide, so every candidate's DMA windows start on a
+    tile; this needs ``par_time``."""
     out = []
     if ndim == 1:
         return [()]                  # stream-only: nothing to block
+    if align:
+        halo = rad * (par_time or 1)
+        caps = (1 << 14,) if ndim == 2 else (512, 1 << 12)
+        per_dim = [[c + 2 * (-(-halo // a) * a)
+                    for c in _aligned_extents(d, a, cap)]
+                   for d, a, cap in zip(dims[1:], align, caps)]
+        return [tuple(bs) for bs in itertools.product(*per_dim)]
     if ndim == 2:
         b = LANE * 2
         while b <= max(2 * LANE, min(dims[1], 1 << 14)):
@@ -301,9 +372,10 @@ def superstep_traffic_bytes(geom: BlockGeometry, num_read: int, num_write: int,
     """
     # Out-of-bound clip, generalizing paper Eq. (7) to any rank:
     read_cells = geom.stream_dim
-    for n, b, c, d in zip(geom.bnum, geom.bsize, geom.csize, geom.blocked_dims):
-        # last block extends past the grid by (n*c + 2*halo - d) cells; those
+    for n, b, c, d, p in zip(geom.bnum, geom.bsize, geom.csize,
+                             geom.blocked_dims, geom.pad):
+        # last block extends past the grid by (n*c + 2*pad - d) cells; those
         # reads are clipped (DMA clamp), so the per-dim read extent is:
-        per_dim = n * b - max(0, (n * c + 2 * geom.size_halo) - d)
+        per_dim = n * b - max(0, (n * c + 2 * p) - d)
         read_cells *= per_dim
     return (read_cells * num_read + geom.cells_written * num_write) * cell_bytes

@@ -39,7 +39,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.core.blocking import BlockGeometry
 from repro.core.engine import (blocked_superstep, blocked_superstep_chain,
                                blocked_superstep_dag)
@@ -60,14 +59,14 @@ def _linear_index(axis_names: Tuple[str, ...]) -> jnp.ndarray:
     """Linearized shard index over (possibly several) mesh axes."""
     idx = jax.lax.axis_index(axis_names[0])
     for name in axis_names[1:]:
-        idx = idx * compat.axis_size(name) + jax.lax.axis_index(name)
+        idx = idx * jax.lax.axis_size(name) + jax.lax.axis_index(name)
     return idx
 
 
 def _axis_total(axis_names: Tuple[str, ...]) -> int:
     n = 1
     for name in axis_names:
-        n *= compat.axis_size(name)
+        n *= jax.lax.axis_size(name)
     return n
 
 
@@ -356,7 +355,7 @@ def build_distributed_fn(stencil: Stencil, dims, iters: Optional[int],
         def local_run(g, aux_l, coeffs_l):
             return local_impl(g, aux_l, coeffs_l, iters)
         in_specs = (grid_spec, aux_spec, P())
-    shmapped = compat.shard_map(local_run, mesh=mesh, in_specs=in_specs,
+    shmapped = jax.shard_map(local_run, mesh=mesh, in_specs=in_specs,
                                 out_specs=grid_spec, check_vma=False)
     return jax.jit(shmapped,
                    in_shardings=(NamedSharding(mesh, grid_spec),

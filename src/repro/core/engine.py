@@ -41,10 +41,10 @@ def _pad_blocked_dims(grid: jnp.ndarray, geom: BlockGeometry,
     wrap (their only materialization — no per-sub-step re-imposition); other
     kinds pad per their rule and are refreshed by ``_reclamp`` each sub-step.
     """
-    h = geom.size_halo
     kinds = boundary.kinds_of(bc, geom.ndim)
     out = grid
-    for i, (d, p) in enumerate(zip(geom.blocked_dims, geom.padded_dims)):
+    for i, (d, p, h) in enumerate(zip(geom.blocked_dims, geom.padded_dims,
+                                      geom.pad)):
         out = boundary.pad_axis(out, i + 1, h, p - d - h, kinds[i + 1],
                                 boundary.fill_of(bc))
     return out
@@ -74,10 +74,10 @@ def extract_blocks(grid: jnp.ndarray, geom: BlockGeometry,
 def stitch_blocks(blocks: jnp.ndarray, geom: BlockGeometry) -> jnp.ndarray:
     """Write-back: keep each block's compute region, discard halos and
     out-of-bound columns (paper's masked writes)."""
-    h = geom.size_halo
     nb = geom.ndim - 1
     comp = blocks[(slice(None),) * (nb + 1)
-                  + tuple(slice(h, h + c) for c in geom.csize)]
+                  + tuple(slice(h, h + c)
+                          for h, c in zip(geom.pad, geom.csize))]
     # (bn0, .., stream, cs0, ..) -> (stream, bn0, cs0, bn1, cs1, ..)
     perm = (nb,) + tuple(x for i in range(nb) for x in (i, nb + 1 + i))
     out = jnp.transpose(comp, perm).reshape(
@@ -110,7 +110,6 @@ def _reclamp(block: jnp.ndarray, bidx, geom: BlockGeometry,
     the whole halo-extended shard) or a *true* grid boundary (BC at the halo
     offset). Entries may be traced. None = BC at the grid edges.
     """
-    h = geom.size_halo
     kinds = boundary.kinds_of(bc, geom.ndim)
     value = boundary.fill_of(bc)
     if bounds is not None and kinds[0] != "periodic":
@@ -123,8 +122,8 @@ def _reclamp(block: jnp.ndarray, bidx, geom: BlockGeometry,
         else:
             block = jnp.take(block, boundary.map_index(idx, lo, hi, kinds[0]),
                              axis=0)
-    for i, (dim, b, c) in enumerate(zip(geom.blocked_dims, geom.bsize,
-                                        geom.csize)):
+    for i, (dim, b, c, h) in enumerate(zip(geom.blocked_dims, geom.bsize,
+                                           geom.csize, geom.pad)):
         kind = kinds[i + 1]
         if kind == "periodic":
             continue

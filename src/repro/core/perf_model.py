@@ -28,7 +28,7 @@ from typing import Sequence
 
 from repro.core.blocking import (BlockGeometry, bsize_feasible,
                                  choose_bsize_candidates, extended_geometry,
-                                 superstep_traffic_bytes)
+                                 superstep_traffic_bytes, tpu_tiles)
 from repro.core.precision import sublanes_for
 from repro.core.stencils import Stencil
 
@@ -51,8 +51,10 @@ def par_vec_candidates(cell_bytes: int = 4):
 
 @dataclasses.dataclass(frozen=True)
 class Device:
-    """Per-chip hardware constants. Defaults: TPU v5e-class (see DESIGN.md §7)."""
+    """Per-chip hardware constants. Defaults: TPU v5e (see DESIGN.md §7)."""
     name: str = "tpu_v5e"
+    #: ``jax.devices()[0].device_kind`` of this chip
+    kind: str = "TPU v5 lite"
     mem_bw: float = 819e9            # HBM bytes/s
     vpu_flops: float = 12.3e12       # f32 vector FLOP/s (assumed MXU_bf16/16)
     mxu_flops_bf16: float = 197e12   # MXU peak (LM roofline uses this)
@@ -63,21 +65,51 @@ class Device:
     #: ``(1, bsize)`` row stream cannot saturate ``mem_bw``: at V=1 the
     #: kernels issue one descriptor per row per block per stream)
     dma_issue_s: float = 2e-8
+    #: where the peaks come from (``vpu_flops``, ``vmem_budget`` and
+    #: ``dma_issue_s`` are model assumptions, not published figures)
+    source: str = ("Google Cloud TPU docs, 'TPU v5e': 197 TFLOP/s bf16, "
+                   "16 GB HBM at 819 GB/s")
 
     def scaled(self, **kw) -> "Device":
         return dataclasses.replace(self, **kw)
 
 
-# Projection targets (paper §6.3 analogue: model-driven next-gen estimates).
+# The one table of chips the model knows, keyed by name; ``DEVICE_KINDS``
+# indexes it by the ``device_kind`` JAX reports.
 TPU_V5E = Device()
-TPU_V5P = Device(name="tpu_v5p", mem_bw=2765e9, vpu_flops=28.7e12,
-                 mxu_flops_bf16=459e12, vmem_budget=64 * 2 ** 20,
-                 ici_bw=100e9, hbm_bytes=95 * 2 ** 30)
-TPU_V6E = Device(name="tpu_v6e", mem_bw=1640e9, vpu_flops=57.4e12,
-                 mxu_flops_bf16=918e12, vmem_budget=64 * 2 ** 20,
-                 ici_bw=90e9, hbm_bytes=32 * 2 ** 30)
+TPU_V5P = Device(name="tpu_v5p", kind="TPU v5", mem_bw=2765e9,
+                 vpu_flops=28.7e12, mxu_flops_bf16=459e12,
+                 vmem_budget=64 * 2 ** 20, ici_bw=100e9,
+                 hbm_bytes=95 * 2 ** 30,
+                 source="Google Cloud TPU docs, 'TPU v5p': 459 TFLOP/s "
+                        "bf16, 95 GB HBM at 2765 GB/s")
+TPU_V6E = Device(name="tpu_v6e", kind="TPU v6 lite", mem_bw=1640e9,
+                 vpu_flops=57.4e12, mxu_flops_bf16=918e12,
+                 vmem_budget=64 * 2 ** 20, ici_bw=90e9,
+                 hbm_bytes=32 * 2 ** 30,
+                 source="Google Cloud TPU docs, 'TPU v6e': 918 TFLOP/s "
+                        "bf16, 32 GB HBM at 1640 GB/s")
 
 DEVICES = {d.name: d for d in (TPU_V5E, TPU_V5P, TPU_V6E)}
+DEVICE_KINDS = {d.kind: d for d in DEVICES.values()}
+
+#: the named target planning prices against where no TPU is attached (a
+#: model input, not a measurement)
+DEFAULT_TARGET = "tpu_v5e"
+
+
+def attached_device() -> Device:
+    """The :class:`Device` of the attached chip, from JAX's
+    ``device_kind``; :data:`DEFAULT_TARGET` when the backend is not a TPU.
+    A TPU kind missing from the table is an error, never a default."""
+    import jax
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        return DEVICES[DEFAULT_TARGET]
+    if dev.device_kind not in DEVICE_KINDS:
+        raise ValueError(f"no Device entry for TPU kind "
+                         f"{dev.device_kind!r}; known: {sorted(DEVICE_KINDS)}")
+    return DEVICE_KINDS[dev.device_kind]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -106,7 +138,8 @@ def predict(stencil: Stencil, dims: Sequence[int], iters: int,
             bsize, par_time: int, device: Device = TPU_V5E,
             cell_bytes: int = 4, n_chips: int = 1,
             chip_grid: Sequence[int] | None = None,
-            batch: int = 1, bc=None, par_vec: int = 1) -> Prediction:
+            batch: int = 1, bc=None, par_vec: int = 1,
+            aligned: bool = False) -> Prediction:
     """Paper Eqs. (3)-(9) + compute/collective terms.
 
     ``par_vec`` (paper Eq. 7's vector width, V): the kernels stream V
@@ -138,6 +171,9 @@ def predict(stencil: Stencil, dims: Sequence[int], iters: int,
     wrap-around ring: per-chip halo bytes are unchanged (interior shards
     already sent both strips, which is what ``t_halo`` prices as the
     critical path), so only the memory/compute terms move.
+
+    ``aligned`` prices the geometry the compiled kernels run: halos and
+    compute extents rounded to the TPU tiles (:func:`tpu_tiles`).
     """
     if batch < 1:
         raise ValueError(f"batch must be >= 1, got {batch}")
@@ -149,7 +185,9 @@ def predict(stencil: Stencil, dims: Sequence[int], iters: int,
         cg = tuple(chip_grid) if chip_grid else (n_chips,) + (1,) * (len(dims) - 1)
         local_dims = tuple(math.ceil(d / c) for d, c in zip(dims, cg))
     geom = BlockGeometry(len(dims), local_dims, stencil.radius, par_time,
-                         bsize, par_vec)
+                         tuple(bsize), par_vec,
+                         tpu_tiles(len(dims), cell_bytes)[1] if aligned
+                         else ())
     # periodic stream BC: the kernels stream 2*size_halo extra rows/planes
     # per super-step (the materialized wrap) — bill traffic/compute on the
     # extended geometry, report the caller-visible one
@@ -228,7 +266,8 @@ def autotune(stencil: Stencil, dims: Sequence[int], iters: int,
              bsize: Sequence[int] | None = None,
              par_vec: int | None = None,
              par_vecs: Sequence[int] | None = None,
-             top_k: int | None = None, bc=None) -> list:
+             top_k: int | None = None, bc=None,
+             aligned: bool = False) -> list:
     """Design-space pruning (paper §5.3): enumerate power-of-two bsize ×
     par_time × par_vec, drop configs whose working set exceeds the VMEM
     budget, rank by predicted run time. Returns predictions sorted best-first.
@@ -240,7 +279,11 @@ def autotune(stencil: Stencil, dims: Sequence[int], iters: int,
     f32, V<=32 for 16-bit cells).  ``top_k`` keeps only the
     best-ranked predictions — the shortlist the measured tuner
     (``repro.api.tuner``) times on real hardware.  May return ``[]`` when
-    nothing is feasible — callers must not index blindly."""
+    nothing is feasible — callers must not index blindly.
+
+    ``aligned`` restricts the sweep to geometries the compiled kernels
+    accept (:func:`tpu_tiles`): tile-aligned halos and compute extents, and
+    ``par_vec`` a multiple of the stream tile."""
     if par_time is not None:
         pts = [par_time]
     else:
@@ -252,19 +295,24 @@ def autotune(stencil: Stencil, dims: Sequence[int], iters: int,
         # 16-bit cells sweep up to V=32 (the 16-sublane tile ceiling)
         par_vecs = par_vec_candidates(cell_bytes)
     pvs = [par_vec] if par_vec is not None else list(par_vecs)
+    stream_tile, align = (tpu_tiles(len(dims), cell_bytes) if aligned
+                          else (1, ()))
+    pvs = [pv for pv in pvs if pv % stream_tile == 0]
     cands = []
     for pt in pts:
         if bsize is not None:
             # feasibility mirrors choose_bsize_candidates' filter
             bss = ([tuple(bsize)]
-                   if bsize_feasible(stencil.radius, pt, bsize) else [])
+                   if bsize_feasible(stencil.radius, pt, bsize, align)
+                   else [])
         else:
-            bss = choose_bsize_candidates(len(dims), dims, stencil.radius, pt)
+            bss = choose_bsize_candidates(len(dims), dims, stencil.radius, pt,
+                                          align)
         for bs in bss:
             for pv in pvs:
                 p = predict(stencil, dims, iters, bs, pt, device,
                             cell_bytes, n_chips, chip_grid, bc=bc,
-                            par_vec=pv)
+                            par_vec=pv, aligned=aligned)
                 if p.vmem_bytes <= device.vmem_budget:
                     cands.append(p)
     cands.sort(key=lambda p: p.run_time)

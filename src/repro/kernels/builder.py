@@ -25,7 +25,7 @@ Architecture (see DESIGN.md §2 and §2.5):
     *difference* where an edge skips levels (a diamond's short branch);
   * fan-out is one producer window tapped by several consumers (no copies);
     each consumer re-imposes *its own* blocked-axis BC on every slab it
-    reads, and applies its stream-axis BC in its window gathers;
+    reads, and applies its stream-axis BC in its window reads;
   * entry ``e`` lags the stream head by ``Lag_e = max over inputs of Lag_p
     + R_e`` slabs (the per-PE ``rad``-row lag of the paper, generalized to
     DAG edges and vector slabs);
@@ -49,8 +49,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from repro import compat
 
 from repro.core import precision
 from repro.core.blocking import BlockGeometry, stream_extension
@@ -78,6 +76,25 @@ def _chain_lags(chain, par_vec: int):
     return rs, list(itertools.accumulate(rs))
 
 
+@functools.lru_cache(maxsize=None)
+def _stream_shifts(kind: str, dom: int, ds: int) -> tuple:
+    """The nonzero row shifts the stream-axis BC ``kind`` applies to tap
+    offset ``ds`` over a ``dom``-row stream: for every real output row
+    ``o``, the source row is ``map(o + ds)``, i.e. the interior row shifted
+    by ``map(o + ds) - (o + ds)``.  Static, so the kernel selects among
+    static window slices instead of gathering at traced positions."""
+    if kind == "constant":
+        return ()
+    r = np.arange(dom) + ds
+    m = r
+    if kind == "reflect":
+        p = max(2 * dom - 2, 1)
+        m = np.mod(r, p)
+        m = np.where(m >= dom, p - m, m)
+    d = np.unique(np.clip(m, 0, dom - 1) - r)
+    return tuple(int(x) for x in d if x)
+
+
 def _dag_kernel(*refs, plan, lay, geom: BlockGeometry, ns: int, dom: int,
                 sdtype=jnp.float32):
     # mixed precision (repro.core.precision): every VMEM buffer — windows,
@@ -96,7 +113,7 @@ def _dag_kernel(*refs, plan, lay, geom: BlockGeometry, ns: int, dom: int,
     entries = plan.entries
     BS = geom.bsize
     CS = geom.csize
-    h = geom.size_halo
+    P = geom.pad                             # per-axis (tile-aligned) halo
     radii, lags, wins = lay.radii, lay.lags, lay.wins
     HA = lay.aux_depth                       # aux window depth, in slabs
     nslabs = ns // V
@@ -130,7 +147,8 @@ def _dag_kernel(*refs, plan, lay, geom: BlockGeometry, ns: int, dom: int,
 
     starts = tuple(pl.program_id(d) * CS[d] for d in range(nb))
     steps = steps_ref[0, 0]
-    iv = jax.lax.iota(jnp.int32, V)          # row offsets within a slab
+    # row offsets within a slab, broadcastable over a (V, *BS) slab
+    iv = jax.lax.broadcasted_iota(jnp.int32, (V,) + (1,) * nb, 0)
 
     # --- per-stage coefficient dicts (shared across par_time repeats) -------
     # built at kernel top level: values read inside a pl.when branch must not
@@ -146,54 +164,77 @@ def _dag_kernel(*refs, plan, lay, geom: BlockGeometry, ns: int, dom: int,
         return cdicts[entry.coeff_lo]
 
     # --- blocked-axis boundary re-imposition, per consuming entry's BC ------
-    # (only grid-edge blocks ever act; applied to every slab an entry reads,
-    # so fan-out consumers each see their own BC on a shared producer)
+    # Only grid-edge blocks act, and which blocks those are is static: block
+    # i of axis ax holds the first real column at lo_i = P - i*CS and the
+    # last at hi_i = d-1 + P - i*CS.  Band columns are therefore static
+    # slices picked by the block index — no value-level dynamic slicing.
+    pids = [pl.program_id(ax) for ax in range(nb)]
     iotas = [jax.lax.broadcasted_iota(jnp.int32, (V,) + BS, 1 + ax)
              for ax in range(nb)]
-    los = tuple(h - s for s in starts)
-    his = tuple((d - 1) + h - s for d, s in zip(geom.blocked_dims, starts))
+    los = tuple(p_ - pid * c for p_, pid, c in zip(P, pids, CS))
+    his = tuple((d - 1) + p_ - pid * c
+                for d, p_, pid, c in zip(geom.blocked_dims, P, pids, CS))
+    edge_lo = [[(i, P[ax] - i * CS[ax]) for i in range(geom.bnum[ax])
+                if P[ax] - i * CS[ax] >= 1] for ax in range(nb)]
+    edge_hi = [[(i, hi) for i in range(geom.bnum[ax])
+                for hi in [geom.blocked_dims[ax] - 1 + P[ax] - i * CS[ax]]
+                if hi <= BS[ax] - 2] for ax in range(nb)]
 
-    def _reimpose_axis(slab, kind, ax, fill):
+    def _band(slab, ax, edges, off):
+        """Column ``c_i + off`` of ``slab`` along blocked axis ``ax``, for
+        whichever edge block ``(i, c_i)`` of ``edges`` this program is (a
+        width-1 slab that broadcasts along the axis)."""
+        band = None
+        for i, c in edges:
+            col = jax.lax.slice_in_dim(slab, c + off, c + off + 1,
+                                       axis=1 + ax)
+            band = col if band is None else jnp.where(pids[ax] == i, col,
+                                                      band)
+        return band
+
+    def _reimpose_axis(slab, kind, ax, fill, rad):
         if kind == "periodic":
             # wrap-padded halos are exact translated copies: no re-imposition
             return slab
-        n, axis = BS[ax], 1 + ax
         lo, hi, iota = los[ax], his[ax], iotas[ax]
         if kind == "constant":
             slab = jnp.where(iota < lo, fill, slab)
             return jnp.where(iota > hi, fill, slab)
+        elo, ehi = edge_lo[ax], edge_hi[ax]
         if kind == "reflect":
-            flipped = jnp.flip(slab, axis=axis)
-            mlo = jnp.roll(flipped, 2 * lo + 1 - n, axis=axis)
-            mhi = jnp.roll(flipped, 2 * hi + 1 - n, axis=axis)
-            slab = jnp.where(iota < lo, mlo, slab)
-            return jnp.where(iota > hi, mhi, slab)
-        sizes = tuple(1 if a == axis else s
-                      for a, s in enumerate((V,) + BS))
-        at = lambda p_: tuple(p_ if a == axis else 0     # noqa: E731
-                              for a in range(1 + nb))
-        lo_band = jax.lax.dynamic_slice(slab, at(jnp.clip(lo, 0, n - 1)),
-                                        sizes)
-        hi_band = jax.lax.dynamic_slice(slab, at(jnp.clip(hi, 0, n - 1)),
-                                        sizes)
-        slab = jnp.where(iota < lo, lo_band, slab)
-        return jnp.where(iota > hi, hi_band, slab)
+            # only the rad columns the consuming stencil taps past the edge
+            # reach a real cell; column lo-k mirrors lo+k, hi+k mirrors hi-k
+            for k in range(1, rad + 1):
+                if elo:
+                    slab = jnp.where(iota == lo - k, _band(slab, ax, elo, k),
+                                     slab)
+                if ehi:
+                    slab = jnp.where(iota == hi + k,
+                                     _band(slab, ax, ehi, -k), slab)
+            return slab
+        if elo:
+            slab = jnp.where(iota < lo, _band(slab, ax, elo, 0), slab)
+        if ehi:
+            slab = jnp.where(iota > hi, _band(slab, ax, ehi, 0), slab)
+        return slab
 
-    def reclamp_for(bc):
+    def reclamp_for(entry):
+        bc = entry.bc
         kinds = ("clamp",) * nb if bc is None else tuple(bc.kinds[1:])
         fill = 0.0 if bc is None else bc.value
+        rad = 0 if entry.stencil is None else entry.stencil.radius
 
         def reclamp(slab):
             for ax in range(nb):
-                slab = _reimpose_axis(slab, kinds[ax], ax, fill)
+                slab = _reimpose_axis(slab, kinds[ax], ax, fill, rad)
             return slab
         return reclamp
 
-    reclamps = [reclamp_for(e.bc) for e in entries]
+    reclamps = [reclamp_for(e) for e in entries]
 
     # --- DMA plumbing --------------------------------------------------------
     in_idx = tuple(pl.ds(s, b) for s, b in zip(starts, BS))
-    out_idx = tuple(pl.ds(s + h, c) for s, c in zip(starts, CS))
+    out_idx = tuple(pl.ds(s + p_, c) for s, p_, c in zip(starts, P, CS))
 
     def in_copy(kf, j, slot):
         src = jnp.clip(j, 0, nslabs - 1) * V
@@ -233,7 +274,8 @@ def _dag_kernel(*refs, plan, lay, geom: BlockGeometry, ns: int, dom: int,
             def _(kf=kf, oslot=oslot):   # slot reuse: prior copy must drain
                 out_copy(kf, j - 2, oslot).wait()
 
-            crop = val[(slice(None),) + tuple(slice(h, h + c) for c in CS)]
+            crop = val[(slice(None),)
+                       + tuple(slice(p_, p_ + c) for p_, c in zip(P, CS))]
             if multi:
                 out_buf[kf, oslot] = crop
             else:
@@ -296,8 +338,6 @@ def _dag_kernel(*refs, plan, lay, geom: BlockGeometry, ns: int, dom: int,
                                     read_slab(entry.inputs[0], j),
                                     read_slab(entry.inputs[1], j))
                 else:
-                    base = (j - R) * V   # logical stream row of cat[0]
-                    limit = jnp.minimum((j + R) * V + V - 1, dom - 1)
                     bc = entry.bc
                     kind_s = "clamp" if bc is None else bc.kinds[0]
                     fill = 0.0 if bc is None else bc.value
@@ -330,29 +370,37 @@ def _dag_kernel(*refs, plan, lay, geom: BlockGeometry, ns: int, dom: int,
                         def stream_tap(ds_):
                             """(V, *BS) slab of stream rows ``j*V+ds_ ..``
                             with this entry's stream-axis BC applied per
-                            row: clamp clips, reflect mirrors (the target
-                            provably stays in the window), constant
+                            row: clamp clips, reflect mirrors, constant
                             overrides out-of-domain rows with the fill;
                             periodic was materialized as a stream extension
-                            by the wrapper.  ``limit`` stops reads at the
-                            newest pushed row."""
+                            by the wrapper.  In the domain's interior this
+                            is the static slice of ``cat`` at ``R*V+ds_``;
+                            rows past a stream end take the static slice
+                            shifted by the (static) set of BC shifts."""
+                            at = R * V + ds_
+                            vals = cat[at:at + V]
                             rows = j * V + ds_ + iv
+                            if kind_s == "constant":
+                                oob = (rows < 0) | (rows > dom - 1)
+                                return jnp.where(oob, fill, vals)
+                            shifts = _stream_shifts(kind_s, dom, ds_)
+                            if not shifts:
+                                return vals
                             if kind_s == "reflect":
                                 p_ = max(2 * dom - 2, 1)
                                 m = jnp.mod(rows, p_)
-                                rows_m = jnp.where(m >= dom, p_ - m, m)
+                                m = jnp.where(m >= dom, p_ - m, m)
                             else:
-                                rows_m = rows
-                            pos = jnp.clip(rows_m, 0, limit) - base
-                            vals = jnp.take(cat, pos, axis=0)
-                            if kind_s == "constant":
-                                oob = (rows < 0) | (rows > dom - 1)
-                                vals = jnp.where(
-                                    oob.reshape((V,) + (1,) * nb),
-                                    fill, vals)
+                                m = rows
+                            delta = jnp.clip(m, 0, dom - 1) - rows
+                            for sg in shifts:
+                                assert 0 <= at + sg <= 2 * R * V, (ds_, sg)
+                                vals = jnp.where(delta == sg,
+                                                 cat[at + sg:at + sg + V],
+                                                 vals)
                             return vals
 
-                        # tap memo: one window gather per distinct stream
+                        # tap memo: one window slice per distinct stream
                         # offset, one lane/sublane rotate per full offset
                         taps = {}
                         zero = (0,) * nb
@@ -420,6 +468,34 @@ def _dag_kernel(*refs, plan, lay, geom: BlockGeometry, ns: int, dom: int,
         out_copy(kf, nslabs - 1, (nslabs - 1) % 2).wait()
 
 
+#: scoped-VMEM limit handed to Mosaic (its default, 16 MiB on v5e, is below
+#: what autotune admits): the scratch buffers, which autotune keeps within
+#: ``Device.vmem_budget`` (32 MiB on v5e), plus the compiler's temporaries
+VMEM_LIMIT_BYTES = 64 * 2 ** 20
+
+
+def _scratch_shapes(dag: DagSpec, geom: BlockGeometry, sdtype, lay=None):
+    """The kernel's scratch buffers, in the order ``_dag_kernel`` unpacks
+    them: one rolling window per consumed producer value (buffer-depth
+    sized), the input double buffer, the aux window and its double buffer,
+    the output double buffer, and their DMA semaphores."""
+    V, BS, CS = geom.par_vec, geom.bsize, geom.csize
+    F = dag.n_fields
+    if lay is None:
+        lay = dag_layout(unroll_dag(dag, geom.par_time), V)
+    scratch = [pltpu.VMEM((w * V,) + BS, sdtype) for w in lay.wins if w > 0]
+    lead = (F,) if F > 1 else ()
+    scratch += [pltpu.VMEM(lead + (2, V) + BS, sdtype),  # in dbl buffer
+                pltpu.SemaphoreType.DMA(lead + (2,))]
+    if any(st.has_aux for st, _, _ in dag.stages):
+        scratch += [pltpu.VMEM((lay.aux_depth * V,) + BS, sdtype),
+                    pltpu.VMEM((2, V) + BS, sdtype),
+                    pltpu.SemaphoreType.DMA((2,))]
+    scratch += [pltpu.VMEM(lead + (2, V) + CS, sdtype),  # out dbl buffer
+                pltpu.SemaphoreType.DMA(lead + (2,))]
+    return scratch
+
+
 def _superstep_dag_impl(dag: DagSpec, geom: BlockGeometry, gp: jnp.ndarray,
                         coeffs_packed: jnp.ndarray, steps: jnp.ndarray,
                         aux_p: Optional[jnp.ndarray], interpret: bool,
@@ -442,26 +518,13 @@ def _superstep_dag_impl(dag: DagSpec, geom: BlockGeometry, gp: jnp.ndarray,
     plan = unroll_dag(dag, geom.par_time)
     lay = dag_layout(plan, V)
     has_aux = any(st.has_aux for st, _, _ in dag.stages)
-    BS, CS = geom.bsize, geom.csize
 
     # every VMEM buffer holds the STORAGE dtype (bf16 windows halve the
     # working set); the kernel widens reads to f32 for the stage arithmetic
     sdtype = gp.dtype
     kernel = functools.partial(_dag_kernel, plan=plan, lay=lay, geom=geom,
                                ns=ns, dom=dom, sdtype=sdtype)
-    # one rolling window per consumed producer value, buffer-depth sized
-    scratch = [pltpu.VMEM((w * V,) + BS, sdtype)
-               for w in lay.wins if w > 0]
-    lead = (F,) if multi else ()
-    scratch += [pltpu.VMEM(lead + (2, V) + BS, sdtype),  # in dbl buffer
-                pltpu.SemaphoreType.DMA(lead + (2,))]
-    if has_aux:
-        scratch += [pltpu.VMEM((lay.aux_depth * V,) + BS, sdtype),
-                    pltpu.VMEM((2, V) + BS, sdtype),
-                    pltpu.SemaphoreType.DMA((2,))]
-    scratch += [pltpu.VMEM(lead + (2, V) + CS, sdtype),  # out dbl buffer
-                pltpu.SemaphoreType.DMA(lead + (2,))]
-
+    scratch = _scratch_shapes(dag, geom, sdtype, lay)
     n_hbm_in = 2 if has_aux else 1
     operands = (coeffs_packed.reshape(1, -1), gp) + (
         (aux_p,) if has_aux else ())
@@ -477,9 +540,10 @@ def _superstep_dag_impl(dag: DagSpec, geom: BlockGeometry, gp: jnp.ndarray,
         scratch_shapes=scratch,
         out_shape=jax.ShapeDtypeStruct(gp.shape, sdtype),
         interpret=interpret,
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=(
-                ("parallel" if block_parallel else "arbitrary",) * len(grid))),
+                ("parallel" if block_parallel else "arbitrary",) * len(grid)),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
     )(steps_arr, *operands)
 
 

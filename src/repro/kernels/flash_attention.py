@@ -36,7 +36,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
 
 NEG_INF = -1e30
 DEFAULT_BLOCK_Q = 512
@@ -149,7 +148,7 @@ def _flash_fwd_pallas(q, k, v, causal: bool, block_q: int, block_kv: int,
             jax.ShapeDtypeStruct((B * H, Sq, 1), jnp.float32),
         ],
         interpret=interpret,
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
     )(qt, kt, vt)
     o = o.reshape(B, H, Sq, D).transpose(0, 2, 1, 3)
@@ -273,7 +272,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal: bool, block_q: int,
         out_specs=pl.BlockSpec((None, block_q, D), lambda h, i: (h, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B * H, Sq, D), q.dtype),
         interpret=interpret,
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
     )(qt, kt, vt, dot, lset, dltt)
 
@@ -299,7 +298,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal: bool, block_q: int,
             jax.ShapeDtypeStruct((B * H, Skv, D), jnp.float32),
         ],
         interpret=interpret,
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
     )(qt, kt, vt, dot, lset, dltt)
 

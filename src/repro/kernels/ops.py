@@ -59,12 +59,12 @@ def _pad_blocked(grid: jnp.ndarray, geom: BlockGeometry,
     domain first).  Leading batch axes (in front of the streaming axis) are
     left untouched.
     """
-    h = geom.size_halo
     kinds = boundary.kinds_of(bc, geom.ndim)
     fill = boundary.fill_of(bc)
     lead = grid.ndim - (geom.ndim - 1)       # batch axes + streaming axis
     out = grid
-    for i, (d, p) in enumerate(zip(geom.blocked_dims, geom.padded_dims)):
+    for i, (d, p, h) in enumerate(zip(geom.blocked_dims, geom.padded_dims,
+                                      geom.pad)):
         out = boundary.pad_axis(out, lead + i, h, p - d - h, kinds[i + 1],
                                 fill)
     ext = _stream_ext(geom, bc)
@@ -79,10 +79,9 @@ def _pad_blocked(grid: jnp.ndarray, geom: BlockGeometry,
 
 def _slice_blocked(gp: jnp.ndarray, geom: BlockGeometry,
                    bc=None) -> jnp.ndarray:
-    h = geom.size_halo
     ext = _stream_ext(geom, bc)
     idx = ((Ellipsis, slice(ext, ext + geom.stream_dim))
-           + tuple(slice(h, h + d) for d in geom.blocked_dims))
+           + tuple(slice(h, h + d) for h, d in zip(geom.pad, geom.blocked_dims)))
     return gp[idx]
 
 
@@ -98,7 +97,6 @@ def _reclamp_padded(gp: jnp.ndarray, geom: BlockGeometry,
     is wasted work and, for the constant BC, would wrongly treat real edge
     columns as ghost positions (the zero-pad seam case — e.g. a stream-only
     stencil embedded in a higher-rank grid)."""
-    h = geom.size_halo
     kinds = boundary.kinds_of(bc, geom.ndim)
     fill = boundary.fill_of(bc)
     ext = _stream_ext(geom, bc)
@@ -110,7 +108,8 @@ def _reclamp_padded(gp: jnp.ndarray, geom: BlockGeometry,
         # themselves (their values are never tapped, only re-computed)
         tail = jnp.arange(d + 2 * ext, gp.shape[axis])
         gp = jnp.take(gp, jnp.concatenate([core, tail]), axis=axis)
-    for i, (d, p) in enumerate(zip(geom.blocked_dims, geom.padded_dims)):
+    for i, (d, p, h) in enumerate(zip(geom.blocked_dims, geom.padded_dims,
+                                      geom.pad)):
         if p == d:
             continue
         axis = gp.ndim - (geom.ndim - 1) + i

@@ -6,7 +6,7 @@ device query.
 """
 from __future__ import annotations
 
-from repro import compat
+import jax
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -14,12 +14,13 @@ def make_production_mesh(*, multi_pod: bool = False):
     Multi-pod: 2 pods x 256 = 512 chips (pod, data, model)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape, axes):
-    """Arbitrary mesh (tests, elastic re-meshing)."""
-    return compat.make_mesh(shape, axes)
+    """Arbitrary mesh (tests, elastic re-meshing), every axis Auto."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def dp_axes(mesh) -> tuple:

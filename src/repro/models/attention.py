@@ -17,7 +17,6 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.models.layers import apply_rope, rms_norm, rope_table, _normal
 from repro.parallel import logical_shard
 
@@ -311,7 +310,7 @@ def _flash_stub_sharded(q, k, v):
     qs = resolve_spec(q.shape, ("batch", "seq", "heads", None), mesh, rules)
     ks = resolve_spec(k.shape, ("batch", "seq", "kv_heads", None), mesh,
                       rules)
-    fn = compat.shard_map(_flash_stub, mesh=mesh, in_specs=(qs, ks, ks),
+    fn = jax.shard_map(_flash_stub, mesh=mesh, in_specs=(qs, ks, ks),
                           out_specs=qs, check_vma=False)
     return fn(q, k, v)
 

@@ -29,7 +29,6 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.models.layers import _normal
 from repro.parallel import current_rules, logical_shard
 
@@ -173,7 +172,7 @@ def _ep_a2a_path(x, p, cfg, mesh, rules):
             got * w_s[:, None].astype(x_l.dtype))
         return out.reshape(Bl, Sl, D), aux
 
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(x_spec, P(None, None), w_in_spec, w_out_spec),
         out_specs=(x_spec, P()), check_vma=False)
@@ -208,7 +207,7 @@ def _ep_bcast_path(x, p, cfg, mesh, rules):
         out = jax.lax.psum(out, ep)
         return out.reshape(Bl, Sl, D), aux
 
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(x_spec, P(None, None),
                   P(ep, None, rules.get("wt_fsdp")),

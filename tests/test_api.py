@@ -372,3 +372,66 @@ def test_plan_errors_clearly_when_nothing_feasible():
     with pytest.raises(ValueError, match="no VMEM-feasible"):
         plan(StencilProblem("diffusion2d", (256, 256)),
              RunConfig(backend="engine", autotune=True, par_time=128))
+
+
+# --- the chip decides: device model, tiles, no interpret knob ----------------
+
+class _FakeDevice:
+    def __init__(self, platform, kind):
+        self.platform, self.device_kind = platform, kind
+
+
+def test_device_resolves_from_attached_device_kind(monkeypatch):
+    from repro.core import perf_model
+
+    def attach(platform, kind):
+        monkeypatch.setattr(jax, "devices",
+                            lambda *a: [_FakeDevice(platform, kind)])
+    attach("tpu", "TPU v5 lite")
+    assert RunConfig().resolved_device() is perf_model.TPU_V5E
+    attach("tpu", "TPU v6 lite")
+    assert RunConfig().resolved_device() is perf_model.TPU_V6E
+    attach("tpu", "TPU v99")            # unknown chips are an error
+    with pytest.raises(ValueError, match="TPU v99"):
+        RunConfig().resolved_device()
+    attach("cpu", "cpu")                # no chip: the named model target
+    assert RunConfig().resolved_device().name == perf_model.DEFAULT_TARGET
+    assert RunConfig(device="tpu_v5p").resolved_device() is perf_model.TPU_V5P
+    kinds = [d.kind for d in perf_model.DEVICES.values()]
+    assert len(set(kinds)) == len(kinds)
+    assert all(d.source for d in perf_model.DEVICES.values())
+
+
+def test_pallas_has_no_interpret_switch():
+    import dataclasses
+    assert "interpret" not in {f.name for f in dataclasses.fields(RunConfig)}
+    with pytest.raises(TypeError):
+        RunConfig(backend="pallas", interpret=True)
+
+
+def test_pallas_plan_is_tile_aligned():
+    p = plan(StencilProblem("diffusion2d", (64, 512)),
+             RunConfig(backend="pallas", par_time=2, bsize=512))
+    assert p.geometry.align == (128,) and p.geometry.pad == (128,)
+    assert p.geometry.par_vec == 8          # the f32 sublane tile
+    with pytest.raises(ValueError, match="par_vec=4"):
+        plan(StencilProblem("diffusion2d", (64, 512)),
+             RunConfig(backend="pallas", par_time=2, bsize=512, par_vec=4))
+    with pytest.raises(ValueError, match="too small"):
+        plan(StencilProblem("diffusion2d", (64, 512)),
+             RunConfig(backend="pallas", par_time=2, bsize=256))
+    # interpret mode runs the geometry as given
+    q = plan(StencilProblem("diffusion2d", (64, 512)),
+             RunConfig(backend="pallas_interpret", par_time=2, bsize=256))
+    assert q.geometry.align == () and q.geometry.pad == (2,)
+
+
+def test_compile_cache_follows_env_var(monkeypatch, tmp_path):
+    from repro import compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert compile_cache.enable_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before   # sets nothing
+    root = compile_cache.CHECKOUT_CACHE.parent
+    assert compile_cache.CHECKOUT_CACHE.name == ".jax_cache"
+    assert (root / "src" / "repro" / "compile_cache.py").is_file()

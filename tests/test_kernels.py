@@ -169,3 +169,126 @@ def test_offsets_span_must_fit_radius():
         Stencil("bad", 2, 1, 1, 1, 1, False, ("c",),
                 lambda get, c, aux=None: get((0, 2)),
                 offsets=((0, 2),))
+
+
+# --- tile-aligned geometries: the compiled kernels' layout, in interpret mode -
+#
+# The compiled path rounds each blocked dim's halo up to its TPU tile so every
+# DMA window starts on a tile (``BlockGeometry.align``).  These cases start
+# from halos and block origins that are NOT tile multiples before alignment,
+# and from stream extents that do not divide by ``par_vec``.
+
+def _aligned_geom(st, dims, par_time, csize, par_vec, cell_bytes=4):
+    from repro.core.blocking import tpu_tiles
+    stream_tile, align = tpu_tiles(st.ndim, cell_bytes)
+    assert par_vec % stream_tile == 0
+    h = st.radius * par_time
+    bsize = tuple(c + 2 * (-(-h // a) * a) for c, a in zip(csize, align))
+    geom = BlockGeometry(st.ndim, dims, st.radius, par_time, bsize, par_vec,
+                         align)
+    for c, p, a in zip(geom.csize, geom.pad, align):
+        assert c % a == 0 and p % a == 0 and p > h   # origins on tiles
+    return geom
+
+
+@pytest.mark.parametrize("bc", ["clamp", "reflect", "constant:0.5",
+                                "periodic"])
+@pytest.mark.parametrize("name,dims,par_time,csize,par_vec,dtype", [
+    ("diffusion2d", (21, 300), 3, (128,), 8, "float32"),
+    ("hotspot2d", (19, 260), 2, (128,), 16, "float32"),
+    ("diffusion2d", (35, 200), 2, (128,), 16, "bfloat16"),
+    ("diffusion3d", (10, 20, 140), 2, (8, 128), 3, "float32"),
+    ("hotspot3d", (9, 13, 150), 3, (8, 128), 2, "float32"),
+])
+def test_aligned_geometry_matches_oracle(bc, name, dims, par_time, csize,
+                                         par_vec, dtype):
+    from repro.kernels.ops import pack_coeffs, run_pallas
+    st = STENCILS[name]
+    cb = jnp.dtype(dtype).itemsize
+    geom = _aligned_geom(st, dims, par_time, csize, par_vec, cb)
+    assert dims[0] % par_vec or name.endswith("3d")
+    problem = StencilProblem(st, dims, dtype=dtype, boundary=bc)
+    stage, bc_obj = problem.exec_stages[0]
+    g, aux = _data(st, dims)
+    g = g.astype(dtype)
+    aux = None if aux is None else aux.astype(dtype)
+    c = problem.resolve_coeffs(dtype=jnp.float32)[0]
+    want = oracle_run(stage, g, c, 7, aux, bc=bc_obj)
+    got = run_pallas(stage, geom, g, pack_coeffs(stage, c), 7, aux, True,
+                     bc=bc_obj)
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(want, np.float32))
+
+
+def test_aligned_wave_dag_matches_oracle():
+    """A two-field DAG (fan-in, per-consumer re-imposition) on the aligned
+    layout, with a periodic stream extension."""
+    from repro.api import StencilProgram, StencilStage
+    from repro.core.stencils import make_combine
+    from repro.kernels.ops import pack_dag_coeffs, run_pallas_dag
+    from repro.kernels.ref import oracle_dag_run
+    lap = StencilStage(make_star(2, 1), name="lapu", inputs=("u",),
+                       coeffs={"c0": -4.0, "c_0_-1": 1.0, "c_0_1": 1.0,
+                               "c_1_-1": 1.0, "c_1_1": 1.0})
+    unext = StencilStage(make_combine(2, 3), name="unext",
+                         inputs=("u", "u_prev", "lapu"),
+                         coeffs={"w0": 2.0, "w1": -1.0, "w2": 0.16})
+    prog = StencilProgram((lap, unext), fields=("u", "u_prev"),
+                          updates={"u": "unext", "u_prev": "u"})
+    dims = (21, 260)
+    problem = StencilProblem(prog, dims, boundary="periodic")
+    dag = problem.exec_dag
+    g, _ = _data(STENCILS["diffusion2d"], dims)
+    state = jnp.stack([g, g[::-1]])
+    coeffs = problem.resolve_coeffs(dtype=jnp.float32)
+    geom = _aligned_geom(make_star(2, 1), dims, 2, (128,), 8)
+    want = oracle_dag_run(dag, state, coeffs, 5, None)
+    got = run_pallas_dag(dag, geom, state, pack_dag_coeffs(dag, coeffs), 5,
+                         None, True)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_dma_traffic_counts_aligned_widths():
+    """The aligned 16384^2 geometry moves its full (tile-rounded) block
+    width in and its compute width out; the alignment's cost against the
+    paper's geometry at the same compute width is the extra halo reads."""
+    st = STENCILS["diffusion2d"]
+    dims = (16384, 16384)
+    new = BlockGeometry(2, dims, 1, 16, (1280,), 16, (128,))
+    old = BlockGeometry(2, dims, 1, 16, (1056,), 16)
+    assert new.pad == (128,) and new.csize == old.csize == (1024,)
+    assert new.padded_dims == (16640,) and new.bnum == old.bnum == (16,)
+    rows = 16384
+    assert dma_traffic_bytes(st, new, 4) == (
+        16 * rows * 1280 + 16 * rows * 1024) * 4
+    assert dma_traffic_bytes(st, new, 4) - dma_traffic_bytes(st, old, 4) \
+        == 16 * rows * (1280 - 1056) * 4
+
+
+@pytest.mark.parametrize("name,dims,dtype", [
+    ("diffusion2d", (16384, 16384), "float32"),
+    ("hotspot2d", (16384, 16384), "bfloat16"),
+    ("diffusion3d", (448, 448, 448), "float32"),
+    ("hotspot3d", (448, 448, 448), "bfloat16"),
+    ("diffusion2d", (40, 300), "float32"),
+])
+def test_aligned_autotune_windows_start_on_tiles(name, dims, dtype):
+    """Every geometry the compiled path may pick has tile-aligned DMA
+    origins (i*csize and i*csize + pad) and a par_vec that fills the
+    stream tile; plan(backend='pallas') compiles one of them."""
+    from repro.core import perf_model
+    from repro.core.blocking import tpu_tiles
+    st = STENCILS[name]
+    cb = jnp.dtype(dtype).itemsize
+    stream_tile, align = tpu_tiles(st.ndim, cb)
+    cands = perf_model.autotune(st, dims, 1000, cell_bytes=cb, aligned=True)
+    assert cands
+    for p in cands:
+        g = p.geom
+        assert g.align == align and g.par_vec % stream_tile == 0
+        assert all(c % a == 0 and h % a == 0
+                   for c, h, a in zip(g.csize, g.pad, align))
+        assert p.vmem_bytes <= perf_model.TPU_V5E.vmem_budget
+    pl_ = plan(StencilProblem(name, dims, dtype=dtype),
+               RunConfig(backend="pallas", autotune="model"))
+    assert pl_.geometry == cands[0].geom

@@ -198,8 +198,9 @@ def test_tolerance_budget_shape():
 #
 # The accumulation casts are emitted ONLY for sub-32-bit storage
 # (precision.needs_accum_cast); f32 traces must be byte-for-byte the same
-# programs as before this feature.  Digests pinned from the pre-bf16 tree
-# (identical across reference/engine/pallas_interpret there and here).
+# programs as before this feature.  Digests re-pinned under jax 0.9.0, whose
+# PRNG draws different inputs than 0.4.37's; the seed tree and this one give
+# the same digests, identical across reference/engine/pallas_interpret.
 
 def _digest(a):
     return hashlib.sha256(
@@ -207,9 +208,9 @@ def _digest(a):
 
 
 F32_GOLDENS = {
-    "diffusion2d": "5e5aa9640930e61c",
-    "hotspot2d": "dc2f4f28e1ca0bc7",
-    "diffusion3d": "c7d1213aac9ca816",
+    "diffusion2d": "afd1f20139cd7979",
+    "hotspot2d": "940e74d5c338deeb",
+    "diffusion3d": "8636d141bc1dcd67",
 }
 
 

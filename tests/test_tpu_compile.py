@@ -1,0 +1,111 @@
+"""Compile the autotuned Pallas kernels for a described TPU v5e.
+
+No chip is attached here: the TPU compiler that ships with jax compiles for
+a topology that is only described (``jax.experimental.topologies``), and
+refuses what the chip would refuse — unaligned DMA windows, primitives
+Mosaic cannot lower, scratch beyond the VMEM limit.  Each case plans a
+problem with ``backend="pallas"`` and ``autotune="model"`` exactly as a user
+would, lowers the executable ``run()`` dispatches to for one described chip,
+compiles it, and checks that the kernel is in the HLO and that the VMEM
+estimate autotune pruned with covers the kernel's scratch buffers.
+
+The topology is described inside a module-scoped fixture, never at import:
+only one process at a time may load the TPU library, and the test workers
+must all collect the same tests.
+"""
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import SingleDeviceSharding
+
+from repro.api import RunConfig, StencilProblem, StencilStage, plan
+from repro.core.precision import sublanes_for
+from repro.core.stencils import make_combine, make_star
+from repro.kernels.builder import _scratch_shapes
+from repro.programs import StencilProgram, chain_dag
+
+
+def scratch_bytes(dag, geom, dtype) -> int:
+    """VMEM bytes of the kernel's scratch buffers as Mosaic tiles them:
+    the minor dim padded to 128 lanes, the second-minor to the dtype's
+    sublanes."""
+    dt = jnp.dtype(dtype)
+    sub = sublanes_for(dt.itemsize)
+    total = 0
+    for buf in _scratch_shapes(dag, geom, dt):
+        if buf.memory_space != pltpu.VMEM:
+            continue                              # DMA semaphores
+        *major, s2, s1 = (1,) * (2 - len(buf.shape)) + tuple(buf.shape)
+        total += (int(np.prod(major)) * (-(-s2 // sub) * sub)
+                  * (-(-s1 // 128) * 128) * dt.itemsize)
+    return total
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:   # noqa: BLE001 — any failure means "cannot"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    # compiles for a described chip are written to the persistent cache but
+    # cannot be read back without one: keep the cache off around them
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+def _wave():
+    lap = StencilStage(make_star(2, 1), name="lapu", inputs=("u",),
+                       coeffs={"c0": -4.0, "c_0_-1": 1.0, "c_0_1": 1.0,
+                               "c_1_-1": 1.0, "c_1_1": 1.0})
+    unext = StencilStage(make_combine(2, 3), name="unext",
+                         inputs=("u", "u_prev", "lapu"),
+                         coeffs={"w0": 2.0, "w1": -1.0, "w2": 0.16})
+    return StencilProgram((lap, unext), fields=("u", "u_prev"),
+                          updates={"u": "unext", "u_prev": "u"})
+
+
+CASES = [(name, shape, dtype, "clamp")
+         for name, shape in (("diffusion2d", (16384, 16384)),
+                             ("hotspot2d", (16384, 16384)),
+                             ("diffusion3d", (448, 448, 448)),
+                             ("hotspot3d", (448, 448, 448)))
+         for dtype in ("float32", "bfloat16")]
+CASES += [("diffusion2d", (4096, 4096), "float32", "reflect"),
+          ("hotspot3d", (256, 256, 256), "float32", "periodic"),
+          ("wave", (8192, 8192), "float32", "periodic")]
+
+
+@pytest.mark.parametrize("name,shape,dtype,bc", CASES,
+                         ids=[f"{n}-{'x'.join(map(str, s))}-{d}-{b}"
+                              for n, s, d, b in CASES])
+def test_autotuned_kernel_compiles_for_v5e(one_chip, name, shape, dtype, bc):
+    stencil = _wave() if name == "wave" else name
+    problem = StencilProblem(stencil, shape, dtype=dtype, boundary=bc)
+    p = plan(problem, RunConfig(backend="pallas", autotune="model"))
+    geom = p.geometry
+    assert geom.align, "the compiled path must plan a tile-aligned geometry"
+
+    def spec(s):
+        return jax.ShapeDtypeStruct(s, jnp.dtype(dtype), sharding=one_chip)
+    aux = spec(shape) if problem.needs_aux else None
+    compiled = p.lower(spec(problem.state_shape), aux=aux).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+    dag = (problem.exec_dag if problem.is_dag
+           else chain_dag(problem.exec_stages))
+    assert p.predicted().vmem_bytes >= scratch_bytes(dag, geom, dtype)
+    assert p.predicted().vmem_bytes <= p.device.vmem_budget

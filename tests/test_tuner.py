@@ -126,7 +126,9 @@ def test_key_differs_per_backend_device_and_pin(tmp_path):
         problem, _cfg(None, iters_hint=999), dev, 1, None) == base
     # interpret-mode timings must never serve compiled plans (or vice versa)
     assert schedule_cache.schedule_key(
-        problem, _cfg(None, interpret=True), dev, 1, None) != base
+        problem, _cfg(None, backend="pallas"), dev, 1, None) != \
+        schedule_cache.schedule_key(
+            problem, _cfg(None, backend="pallas_interpret"), dev, 1, None)
     # sweep-constraining knobs key the cache: a winner tuned under a loose
     # par_time_max must not be served to (and violate) a tighter one
     assert schedule_cache.schedule_key(
