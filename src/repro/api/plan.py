@@ -16,6 +16,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro import tracing
 from repro.api import schedule_cache, tuner
 from repro.api.backends import (ExecuteFn, as_program, check_pallas_dtype,
                                 get_backend, resolve_axis_map)
@@ -184,6 +185,7 @@ def _validate_distributed(problem: StencilProblem, config: RunConfig) -> None:
                   config.mesh)
 
 
+@tracing.span("stencil.plan")
 def plan(problem: StencilProblem, config: Optional[RunConfig] = None,
          ) -> "StencilPlan":
     """Compile ``problem`` under ``config`` into a reusable ``StencilPlan``."""
@@ -209,12 +211,14 @@ def plan(problem: StencilProblem, config: Optional[RunConfig] = None,
                 f"knob; backend={config.backend!r} executes scalar ticks "
                 f"and cannot honor it — pin par_vec only for "
                 f"{list(PAR_VEC_BACKENDS)} (or leave it unset)")
-        if config.autotune == "measure":
-            par_time, bsize, par_vec, cands, from_cache = _resolve_measured(
-                problem, config, device, n_chips, chip_grid)
-        else:
-            par_time, bsize, par_vec, cands = _resolve_schedule(
-                problem, config, device, n_chips, chip_grid)
+        with tracing.span("stencil.plan.autotune"):
+            if config.autotune == "measure":
+                par_time, bsize, par_vec, cands, from_cache = \
+                    _resolve_measured(problem, config, device, n_chips,
+                                      chip_grid)
+            else:
+                par_time, bsize, par_vec, cands = _resolve_schedule(
+                    problem, config, device, n_chips, chip_grid)
         stream_tile, align = _tiles(problem, config)
         if par_vec % stream_tile:
             raise ValueError(
@@ -227,7 +231,8 @@ def plan(problem: StencilProblem, config: Optional[RunConfig] = None,
     except ValueError:
         if config.backend != "reference":
             raise
-    program = as_program(factory(problem, config, geom))
+    with tracing.span("stencil.plan.build"):
+        program = as_program(factory(problem, config, geom))
     return StencilPlan(problem=problem, config=config, geometry=geom,
                        backend=config.backend, device=device,
                        n_chips=n_chips, chip_grid=chip_grid,
@@ -264,6 +269,7 @@ class StencilPlan:
     tuned_from_cache: bool = False
 
     # --- execution ----------------------------------------------------------
+    @tracing.span("stencil.run")
     def run(self, grid, iters: int, coeffs=None, *,
             aux=None, checkpoint_every: Optional[int] = None,
             checkpoint_dir: Optional[str] = None) -> jnp.ndarray:
@@ -338,6 +344,7 @@ class StencilPlan:
         resolved = self.problem.resolve_coeffs(coeffs, dtype=dtype)
         return resolved[0] if self.problem.n_stages == 1 else resolved
 
+    @tracing.span("stencil.run_batch")
     def run_batch(self, grids, iters: int, coeffs=None, *,
                   aux=None) -> jnp.ndarray:
         """Advance a batch of grids ``(B, *shape)`` by ``iters`` time-steps

@@ -159,12 +159,16 @@ def fused_chain_loop(stages, geom: BlockGeometry, gp: jnp.ndarray,
 
     def body(s, g):
         steps = jnp.minimum(par_time, iters - s * par_time)
-        op = superstep_chain(stages, geom, g, coeffs_packed, steps, aux_p,
-                             interpret=interpret,
-                             block_parallel=block_parallel)
-        return _reclamp_padded(op, geom, bc0)
+        with jax.named_scope("stencil.superstep"):
+            op = superstep_chain(stages, geom, g, coeffs_packed, steps, aux_p,
+                                 interpret=interpret,
+                                 block_parallel=block_parallel)
+        with jax.named_scope("stencil.halo_refresh"):
+            return _reclamp_padded(op, geom, bc0)
 
-    return _slice_blocked(jax.lax.fori_loop(0, n_super, body, gp), geom, bc0)
+    out = jax.lax.fori_loop(0, n_super, body, gp)
+    with jax.named_scope("stencil.unpad"):
+        return _slice_blocked(out, geom, bc0)
 
 
 def fused_dag_loop(dag, geom: BlockGeometry, gp: jnp.ndarray,
@@ -185,12 +189,16 @@ def fused_dag_loop(dag, geom: BlockGeometry, gp: jnp.ndarray,
 
     def body(s, g):
         steps = jnp.minimum(par_time, iters - s * par_time)
-        op = superstep_dag(dag, geom, g, coeffs_packed, steps, aux_p,
-                           interpret=interpret,
-                           block_parallel=block_parallel)
-        return _reclamp_padded(op, geom, bc0)
+        with jax.named_scope("stencil.superstep"):
+            op = superstep_dag(dag, geom, g, coeffs_packed, steps, aux_p,
+                               interpret=interpret,
+                               block_parallel=block_parallel)
+        with jax.named_scope("stencil.halo_refresh"):
+            return _reclamp_padded(op, geom, bc0)
 
-    return _slice_blocked(jax.lax.fori_loop(0, n_super, body, gp), geom, bc0)
+    out = jax.lax.fori_loop(0, n_super, body, gp)
+    with jax.named_scope("stencil.unpad"):
+        return _slice_blocked(out, geom, bc0)
 
 
 def fused_superstep_loop(stencil: Stencil, geom: BlockGeometry,
@@ -213,10 +221,11 @@ def run_pallas(stencil: Stencil, geom: BlockGeometry, grid: jnp.ndarray,
 
     ``iters`` is dynamic (traced): one executable per (stencil, geom, bc)
     serves all iteration counts — see :func:`fused_superstep_loop`."""
-    aux_p = _pad_blocked(aux, geom, bc) if aux is not None else None
-    return fused_superstep_loop(stencil, geom, _pad_blocked(grid, geom, bc),
-                                coeffs_packed, iters, aux_p, interpret, bc,
-                                block_parallel)
+    with jax.named_scope("stencil.pad"):
+        aux_p = _pad_blocked(aux, geom, bc) if aux is not None else None
+        gp = _pad_blocked(grid, geom, bc)
+    return fused_superstep_loop(stencil, geom, gp, coeffs_packed, iters,
+                                aux_p, interpret, bc, block_parallel)
 
 
 @partial(jax.jit, static_argnames=("stages", "geom", "interpret",
@@ -229,10 +238,11 @@ def run_pallas_chain(stages, geom: BlockGeometry, grid: jnp.ndarray,
     ``stages`` is the static ``((stencil, bc), ...)`` tuple; padding uses
     stage 0's BC (see :func:`fused_chain_loop`)."""
     bc0 = stages[0][1]
-    aux_p = _pad_blocked(aux, geom, bc0) if aux is not None else None
-    return fused_chain_loop(stages, geom, _pad_blocked(grid, geom, bc0),
-                            coeffs_packed, iters, aux_p, interpret,
-                            block_parallel)
+    with jax.named_scope("stencil.pad"):
+        aux_p = _pad_blocked(aux, geom, bc0) if aux is not None else None
+        gp = _pad_blocked(grid, geom, bc0)
+    return fused_chain_loop(stages, geom, gp, coeffs_packed, iters, aux_p,
+                            interpret, block_parallel)
 
 
 @partial(jax.jit, static_argnames=("dag", "geom", "interpret",
@@ -246,10 +256,11 @@ def run_pallas_dag(dag, geom: BlockGeometry, state: jnp.ndarray,
     ``(F, *shape)`` field stack (the leading field axis rides through
     ``_pad_blocked`` like a batch axis); padding uses stage 0's BC."""
     bc0 = dag.stages[0][1]
-    aux_p = _pad_blocked(aux, geom, bc0) if aux is not None else None
-    return fused_dag_loop(dag, geom, _pad_blocked(state, geom, bc0),
-                          coeffs_packed, iters, aux_p, interpret,
-                          block_parallel)
+    with jax.named_scope("stencil.pad"):
+        aux_p = _pad_blocked(aux, geom, bc0) if aux is not None else None
+        gp = _pad_blocked(state, geom, bc0)
+    return fused_dag_loop(dag, geom, gp, coeffs_packed, iters, aux_p,
+                          interpret, block_parallel)
 
 
 def dma_traffic_bytes(stencil: Stencil, geom: BlockGeometry,

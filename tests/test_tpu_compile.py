@@ -13,6 +13,8 @@ The topology is described inside a module-scoped fixture, never at import:
 only one process at a time may load the TPU library, and the test workers
 must all collect the same tests.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -109,3 +111,44 @@ def test_autotuned_kernel_compiles_for_v5e(one_chip, name, shape, dtype, bc):
            else chain_dag(problem.exec_stages))
     assert p.predicted().vmem_bytes >= scratch_bytes(dag, geom, dtype)
     assert p.predicted().vmem_bytes <= p.device.vmem_budget
+
+
+def _op_names(hlo: str) -> dict:
+    """Instruction name -> the ``op_name`` of its metadata ("" if none)."""
+    out = {}
+    for m in re.finditer(r"^\s*(?:ROOT )?%(\S+) = [^\n]*$", hlo, re.M):
+        on = re.search(r'op_name="([^"]*)"', m.group(0))
+        out[m.group(1)] = on.group(1) if on else ""
+    return out
+
+
+SOLVE_CELLS = [("hotspot2d", (16384, 16384)), ("diffusion3d", (640, 640, 640))]
+
+
+@pytest.mark.parametrize("name,shape", SOLVE_CELLS,
+                         ids=[f"{n}-{'x'.join(map(str, s))}"
+                              for n, s in SOLVE_CELLS])
+def test_super_step_phases_carry_their_scopes(one_chip, name, shape):
+    """The solve cells' programs as compiled for the chip: the kernel keeps
+    the instruction name the benchmark's readers match and sits under
+    ``stencil.superstep``; the halo refresh's gathers sit under
+    ``stencil.halo_refresh``; the final slice under ``stencil.unpad``."""
+    problem = StencilProblem(name, shape, dtype="float32", boundary="clamp")
+    p = plan(problem, RunConfig(backend="pallas", autotune="model"))
+    spec = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+    hlo = p.lower(spec, aux=spec if problem.needs_aux else None) \
+        .compile().as_text()
+    names = _op_names(hlo)
+    kernels = [n for n in names
+               if re.fullmatch(r"superstep_chain(\.\d+)?", n)]
+    assert len(kernels) == 1
+    assert "stencil.superstep/" in names[kernels[0]]
+    kernel_line = re.search(rf"%{re.escape(kernels[0])} = [^\n]*", hlo)
+    assert 'custom_call_target="tpu_custom_call"' in kernel_line.group(0)
+    gathers = [n for n, o in names.items()
+               if n.startswith("fusion") and o.endswith("/gather")]
+    assert gathers
+    assert all("/stencil.halo_refresh/" in names[n] for n in gathers)
+    assert all("/stencil.halo_refresh/" in o
+               for o in names.values() if "jit(_take)" in o)
+    assert any("/stencil.unpad/" in o for o in names.values())
