@@ -19,6 +19,8 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax import lax
 
 from repro.core import boundary
 from repro.core.blocking import BlockGeometry, stream_extension as _stream_ext
@@ -93,38 +95,83 @@ def _reclamp_padded(gp: jnp.ndarray, geom: BlockGeometry,
     layout so a fused super-step loop can carry it — and an enclosing ``jit``
     can donate it — without leaving the padded representation.
 
-    Axes whose pad is zero are skipped outright: a degenerate gather there
-    is wasted work and, for the constant BC, would wrongly treat real edge
-    columns as ghost positions (the zero-pad seam case — e.g. a stream-only
-    stencil embedded in a higher-rank grid)."""
+    Only the padding strips are written, in place (``_refresh_strips``): the
+    periodic stream extension, then each blocked axis in order, so a later
+    axis' strips carry the corners an earlier one refreshed.  The real cells
+    are never read back and rewritten, so the refresh moves bytes in
+    proportion to the strips, not to the array.  The ``par_vec`` pad rows
+    beyond the stream extension are left as they are (their values are never
+    tapped, only re-computed).
+
+    Axes whose pad is zero are skipped outright: there is nothing to write
+    and, for the constant BC, a ghost mask there would wrongly treat real
+    edge columns as ghost positions (the zero-pad seam case — e.g. a
+    stream-only stencil embedded in a higher-rank grid)."""
     kinds = boundary.kinds_of(bc, geom.ndim)
     fill = boundary.fill_of(bc)
     ext = _stream_ext(geom, bc)
+    axis = gp.ndim - geom.ndim                # the streaming axis
     if ext:
-        axis = gp.ndim - geom.ndim
         d = geom.stream_dim
-        core = jnp.mod(jnp.arange(d + 2 * ext) - ext, d) + ext
-        # par_vec pad rows beyond the wrap live past the domain: map them to
-        # themselves (their values are never tapped, only re-computed)
-        tail = jnp.arange(d + 2 * ext, gp.shape[axis])
-        gp = jnp.take(gp, jnp.concatenate([core, tail]), axis=axis)
+        gp = _refresh_strips(gp, axis, ext, d, d + 2 * ext, "periodic", fill)
     for i, (d, p, h) in enumerate(zip(geom.blocked_dims, geom.padded_dims,
                                       geom.pad)):
-        if p == d:
-            continue
-        axis = gp.ndim - (geom.ndim - 1) + i
-        kind = kinds[i + 1]
-        if kind == "constant":
-            pos = jnp.arange(p) - h
-            mask = boundary.out_of_range(pos, 0, d - 1)
-            shape = [1] * gp.ndim
-            shape[axis] = p
-            gp = jnp.where(mask.reshape(shape),
-                           jnp.asarray(fill, gp.dtype), gp)
-        else:
-            idx = boundary.map_index(jnp.arange(p) - h, 0, d - 1, kind) + h
-            gp = jnp.take(gp, idx, axis=axis)
+        if p != d:
+            gp = _refresh_strips(gp, axis + 1 + i, h, d, p, kinds[i + 1],
+                                 fill)
     return gp
+
+
+def _refresh_strips(gp: jnp.ndarray, axis: int, h: int, d: int, p: int,
+                    kind: str, fill: float) -> jnp.ndarray:
+    """Overwrite positions ``[0, h)`` and ``[h + d, p)`` of ``axis`` with the
+    ``kind`` ghost values of the real cells ``[h, h + d)``, one
+    ``dynamic_update_slice`` per strip.  Each strip is built from real cells
+    only: the fill value for ``constant``, else the cells
+    ``boundary.map_index`` names — one edge cell broadcast, a slice or a
+    reversed slice where they are contiguous (always so for ``clamp``, and
+    for ``periodic`` / ``reflect`` strips no wider than the domain), else a
+    strip-sized ``take``."""
+    for lo, hi in ((0, h), (h + d, p)):
+        if hi == lo:
+            continue
+        shape = gp.shape[:axis] + (hi - lo,) + gp.shape[axis + 1:]
+        if kind == "constant":
+            strip = jnp.full(shape, fill, gp.dtype)
+        else:
+            with jax.ensure_compile_time_eval():
+                src = np.asarray(boundary.map_index(
+                    jnp.arange(lo - h, hi - h), 0, d - 1, kind)) + h
+            step = np.diff(src)
+            first, last = int(src[0]), int(src[-1])
+            if (src == first).all():
+                strip = _broadcast_edge(gp, axis, first, hi - lo)
+            elif (step == 1).all():
+                strip = lax.slice_in_dim(gp, first, last + 1, axis=axis)
+            elif (step == -1).all():
+                strip = lax.rev(lax.slice_in_dim(gp, last, first + 1,
+                                                 axis=axis), (axis,))
+            else:
+                strip = jnp.take(gp, src, axis=axis, mode="clip")
+        gp = lax.dynamic_update_slice_in_dim(gp, strip, lo, axis)
+    return gp
+
+
+def _broadcast_edge(gp: jnp.ndarray, axis: int, at: int,
+                    width: int) -> jnp.ndarray:
+    """``width`` copies of position ``at`` of ``axis``.  On the minor axis
+    the edge is sliced from a 2D view (all major axes merged, a bitcast):
+    XLA then reads it into one compact vector, where a slice of the
+    higher-rank array lands in a lane-padded buffer that needs a relayout
+    copy before the broadcast."""
+    if axis == gp.ndim - 1:
+        flat = gp.reshape(-1, gp.shape[-1])
+        edge = lax.slice_in_dim(flat, at, at + 1, axis=1)
+        return jnp.broadcast_to(edge, (flat.shape[0], width)) \
+            .reshape(gp.shape[:-1] + (width,))
+    edge = lax.slice_in_dim(gp, at, at + 1, axis=axis)
+    return jnp.broadcast_to(edge, gp.shape[:axis] + (width,)
+                            + gp.shape[axis + 1:])
 
 
 def fused_chain_loop(stages, geom: BlockGeometry, gp: jnp.ndarray,
@@ -141,12 +188,14 @@ def fused_chain_loop(stages, geom: BlockGeometry, gp: jnp.ndarray,
         computed in-trace and the loop lowers to a dynamic ``while``, so one
         compiled executable serves every iteration count (no per-``iters``
         re-trace in a serving loop).
-      * The carry stays in the padded layout: halos are refreshed in place
-        (``_reclamp_padded``) instead of slice+re-pad round-trips, and a
-        caller that jits this function with ``donate_argnums`` on ``gp`` lets
-        XLA reuse the padded buffer for the loop carry (no copy-on-update) —
-        ``gp`` is an intermediate the backend owns, so donation never
-        invalidates a caller-visible array.
+      * The carry stays in the padded layout: between super-steps
+        ``_reclamp_padded`` writes only the padding strips (halo, overhang,
+        periodic stream extension), in place in the kernel's output, instead
+        of a slice+re-pad round-trip or any pass over the whole array; and
+        a caller that jits this function with ``donate_argnums`` on ``gp``
+        lets XLA reuse the padded buffer for the loop carry — ``gp`` is an
+        intermediate the backend owns, so donation never invalidates a
+        caller-visible array.
 
     Padding, the stream extension and inter-super-step halo refresh use stage
     0's BC: that is the BC the chain's first entry reads the carry under
@@ -179,10 +228,10 @@ def fused_dag_loop(dag, geom: BlockGeometry, gp: jnp.ndarray,
     a stage DAG (:class:`repro.programs.DagSpec`) over the *pre-padded*
     state ``gp`` (``(ns, *padded)`` single-field, ``(F, ns, *padded)``
     multi-field — every field padded identically), returning the unpadded
-    result.  The carry stays padded; halos of all fields are refreshed in
-    one ``_reclamp_padded`` per super-step under stage 0's BC (periodicity
-    is uniform by construction; each entry re-imposes its own BC
-    in-kernel)."""
+    result.  The carry stays padded; the padding strips of all fields are
+    rewritten in place by one ``_reclamp_padded`` per super-step under stage
+    0's BC (periodicity is uniform by construction; each entry re-imposes
+    its own BC in-kernel)."""
     bc0 = dag.stages[0][1]
     par_time = geom.par_time
     n_super = (iters + par_time - 1) // par_time

@@ -19,8 +19,8 @@ program's ``op_name`` metadata instead (``kernels/ops.py``):
                             entry points (the Pallas backend pads op by op
                             before its executable, where no scope reaches);
 * ``stencil.superstep``     the fused streaming kernel of one super-step;
-* ``stencil.halo_refresh``  refreshing the padded carry's halo columns
-                            between super-steps;
+* ``stencil.halo_refresh``  rewriting the padded carry's padding strips
+                            in place between super-steps;
 * ``stencil.unpad``         slicing the result out of the padded carry.
 
 A scope is metadata only: it changes no fusion, layout or code of the

@@ -248,6 +248,64 @@ def test_reclamp_padded_skips_zero_pad_axes():
                                   np.asarray(gp))
 
 
+#: (grid dims, par_time, bsize, par_vec, BC, batch): every kind, a mix, 2D
+#: and 3D, overhang (``bnum * csize > d``: 13 -> 16, 9 -> 12), a periodic
+#: stream extension with ``par_vec`` tail rows (10 + 2*2 -> 16), and halos
+#: wider than their domain (h = 4 over 3 cells, stream extension 4 over 3)
+RECLAMP_CASES = [
+    ((6, 13), 2, (8,), 1, "clamp", ()),
+    ((6, 13), 2, (8,), 1, "reflect", ()),
+    ((6, 13), 2, (8,), 1, "constant:2.5", ()),
+    ((10, 13), 2, (8,), 4, "periodic", ()),
+    ((10, 13), 2, (8,), 4, ("periodic", "reflect"), (2,)),
+    ((6, 13), 2, (8,), 1, ("constant:2.0", "reflect"), (3,)),
+    ((5, 9, 13), 2, (8, 8), 1, "clamp", ()),
+    ((5, 9, 13), 2, (8, 8), 1, "constant:0.3", (2,)),
+    ((7, 9, 13), 2, (8, 8), 2, ("periodic", "constant:1.5", "reflect"), ()),
+    ((7, 9, 13), 2, (8, 8), 2, ("clamp", "periodic", "reflect"), (2,)),
+    ((5, 3), 4, (10,), 1, "periodic", ()),
+    ((5, 3), 4, (10,), 1, "reflect", ()),
+    ((3, 3, 3), 4, (10, 10), 1, "periodic", ()),
+    ((4, 3, 3), 4, (10, 10), 1, ("clamp", "reflect", "periodic"), (2,)),
+]
+
+
+@pytest.mark.parametrize(
+    "dims,par_time,bsize,par_vec,bc_spec,batch", RECLAMP_CASES,
+    ids=[f"{'x'.join(map(str, c[0]))}-T{c[1]}-V{c[3]}-{c[4]}-b{len(c[5])}"
+         for c in RECLAMP_CASES])
+def test_reclamp_padded_equals_repad(dims, par_time, bsize, par_vec, bc_spec,
+                                     batch):
+    """The in-place halo refresh writes exactly what slicing the real cells
+    out and padding them again gives, bit for bit.  Every cell outside the
+    real domain starts as NaN, so a strip built from anything but real cells
+    shows; the real columns of the ``par_vec`` tail rows past the stream
+    extension are left as they were (never tapped, only re-computed)."""
+    from repro.core.blocking import BlockGeometry, stream_extension
+    from repro.kernels.ops import _pad_blocked, _reclamp_padded, \
+        _slice_blocked
+    geom = BlockGeometry(len(dims), dims, 1, par_time, bsize, par_vec)
+    bc = BoundaryCondition.make(bc_spec, len(dims))
+    ext = stream_extension(geom, bc)
+    k = jax.random.PRNGKey(11)
+    grid = jax.random.uniform(k, batch + dims, jnp.float32, 0.5, 2.0)
+    padded = np.asarray(_pad_blocked(grid, geom, bc))
+    real = np.zeros(padded.shape, bool)
+    real[(Ellipsis, slice(ext, ext + dims[0]))
+         + tuple(slice(h, h + d) for h, d in zip(geom.pad, dims[1:]))] = True
+    gp = jnp.asarray(np.where(real, padded, np.nan))
+
+    got = np.asarray(jax.jit(_reclamp_padded, static_argnums=(1, 2))(
+        gp, geom, bc))
+    want = np.asarray(_pad_blocked(_slice_blocked(gp, geom, bc), geom, bc))
+    lead = (slice(None),) * len(batch)
+    head = lead + (slice(0, dims[0] + 2 * ext),)   # rows the refresh keeps
+    np.testing.assert_array_equal(got[head], want[head])
+    tail = lead + (slice(dims[0] + 2 * ext, None),) + tuple(
+        slice(h, h + d) for h, d in zip(geom.pad, dims[1:]))
+    assert np.isnan(got[tail]).all()
+
+
 # --- cache keys: a clamp entry never serves a periodic plan ------------------
 
 def test_schedule_cache_keys_on_bc(tmp_path):
