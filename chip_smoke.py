@@ -289,11 +289,14 @@ def phase_four_chips(seed):
     require(got.sharding.is_equivalent_to(sharding, 2),
             f"result not sharded over the mesh: {got.sharding}")
     require(permutes > 0, "no collective-permute in the distributed program")
+    require("tpu_custom_call" in text,
+            "no streaming kernel in the distributed program")
     g = p.geometry
     log("four_chips.distributed", shape=shape, iters=iters, mesh=(2, 2),
         bsize=g.bsize, par_time=g.par_time, compile_s=compile_s,
         first_call_s=first_s, run_s=run_s, reference_s=ref_s,
-        bytes_in_use=in_use, collective_permutes=permutes,
+        par_vec=g.par_vec, bytes_in_use=in_use,
+        collective_permutes=permutes,
         **check(got, want, "float32", iters))
 
 

@@ -19,8 +19,10 @@ The built-ins registered below:
   ``engine``            pure-JAX blocked engine (core/engine.py)
   ``pallas``            Pallas kernels compiled for TPU (kernels/stencil*.py)
   ``pallas_interpret``  same kernels, interpret mode (CPU-correctness)
-  ``distributed``       shard_map runtime over ``config.mesh``
-                        (core/distributed.py); the mesh is just config
+  ``distributed``       the pallas kernels on every shard of
+                        ``config.mesh`` (core/distributed.py), halo strips
+                        exchanged once per super-step; compiled on TPU
+                        meshes, interpreted elsewhere
 
 Throughput subsystem (the ROADMAP's serving path)
 -------------------------------------------------
@@ -47,13 +49,16 @@ leading batch axis:
 
   * reference/engine vmap the fused super-step loop (the blocked update is
     data-parallel across batch members);
-  * pallas maps the batch *sequentially inside one executable*
-    (``lax.map``) — ``vmap`` over the manual-DMA kernels silently corrupts
-    the per-block DMA offsets (verified), and sequential mapping preserves
-    each kernel instance's exact DMA schedule while still amortizing
-    dispatch and compile across the batch;
-  * distributed replicates the batch axis over the mesh and aggregates all
-    batch members' halos into one exchange per mesh axis per super-step.
+  * pallas runs one super-step loop over the batch's padded carry, each
+    super-step mapping the kernel over the members *sequentially*
+    (``lax.map``, ``kernels/ops._per_member``) — ``vmap`` over the
+    manual-DMA kernels silently corrupts the per-block DMA offsets
+    (verified), and sequential mapping preserves each kernel instance's
+    exact DMA schedule while still amortizing dispatch and compile across
+    the batch;
+  * distributed replicates the batch axis over the mesh, runs the kernel
+    on every member in turn (as pallas does) and aggregates all members'
+    halos into one exchange per mesh axis per super-step.
 
 Buffer donation (``RunConfig.donate``): the pallas backends stage an
 edge-padded copy of the grid, run the whole super-step loop on it, and slice
@@ -470,28 +475,14 @@ def _make_pallas_backend(force_interpret: bool):
             return single(gp, pack(coeffs),
                           jnp.asarray(iters, jnp.int32), aux_p)
 
-        def build_batch(mode):
-            # vmap over the manual-DMA pallas_call mis-addresses the per-block
-            # DMAs (wrong results, verified empirically) — map the batch
-            # sequentially INSIDE one executable instead: one dispatch, one
-            # compile, exact per-instance DMA schedules.
-            def batched(gps, coeffs_packed, iters, aux_p):
-                _note_trace(tag)
-                if mode == "batched":
-                    return jax.lax.map(
-                        lambda ga: run_loop(ga[0], coeffs_packed, iters,
-                                            ga[1]),
-                        (gps, aux_p))
-                return jax.lax.map(
-                    lambda g: run_loop(g, coeffs_packed, iters, aux_p),
-                    gps)
-            return jax.jit(batched, donate_argnums=(0,) if donate else ())
-
         def execute_batch(grids, coeffs, iters, aux=None):
             mode = _aux_mode(problem, aux)
             key = _exec_key(tag, problem, geom, batch=grids.shape[0],
                             aux_mode=mode, extra=extra)
-            fn = get(key, lambda: build_batch(mode))
+            # one loop over the batch's padded carry: each super-step runs
+            # the kernel on every member in turn and refreshes all members'
+            # strips at once
+            fn = get(key, build_single)
             gps = _pad_blocked(grids, geom, bc)
             aux_p = _pad_blocked(aux, geom, bc) if aux is not None else None
             return fn(gps, pack(coeffs),
@@ -511,7 +502,7 @@ def _make_pallas_backend(force_interpret: bool):
                 mode = _aux_mode(problem, aux)
                 fn = get(_exec_key(tag, problem, geom, batch=grid.shape[0],
                                    aux_mode=mode, extra=extra),
-                         lambda: build_batch(mode))
+                         build_single)
             else:
                 fn = single
             return fn.lower(spec(grid, pad), spec(pack(coeffs)),
@@ -552,19 +543,20 @@ def _distributed_backend(problem, config, geom):
     st = problem.stencil
     mesh = config.mesh
     axis_map = resolve_axis_map(problem, config)
-    par_time, bsize = geom.par_time, geom.bsize
     get = _program_cache(config.exec_cache)
-    base_key = ("mesh", _mesh_sig(mesh), "amap", axis_map)
+    mc = config.block_parallel
+    base_key = ("mesh", _mesh_sig(mesh), "amap", axis_map, "mc", mc)
 
     def build(batch, aux_batched):
         return build_distributed_fn(
-            st, problem.shape, None, par_time, bsize, mesh, axis_map,
-            batch=batch, aux_batched=aux_batched,
+            st, problem.shape, None, geom.par_time, geom.bsize, mesh,
+            axis_map, batch=batch, aux_batched=aux_batched,
             trace_hook=lambda: _note_trace("distributed"),
             bc=problem.structural_bc,
             stages=(problem.exec_stages
                     if problem.n_stages > 1 and not problem.is_dag else None),
-            dag=problem.exec_dag if problem.is_dag else None)
+            dag=problem.exec_dag if problem.is_dag else None,
+            par_vec=geom.par_vec, align=geom.align, block_parallel=mc)
 
     def program(batch, aux):
         # built lazily on first call (not at plan time): plan() must stay

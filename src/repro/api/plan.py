@@ -42,26 +42,38 @@ def _chip_layout(problem: StencilProblem, config: RunConfig):
 
 
 #: backends whose executables realize ``par_vec`` (the streaming Pallas
-#: kernels).  The others (engine/reference/distributed) run scalar-tick
-#: code, so sweeping V for them would only distort the (bsize, par_time)
-#: ranking and fill measured-tuning shortlists with V-duplicates.
-PAR_VEC_BACKENDS = ("pallas", "pallas_interpret")
+#: kernels, on one chip or on every shard of a mesh).  The others
+#: (engine/reference) run scalar-tick code, so sweeping V for them would
+#: only distort the (bsize, par_time) ranking and fill measured-tuning
+#: shortlists with V-duplicates.
+PAR_VEC_BACKENDS = ("pallas", "pallas_interpret", "distributed")
 
 #: built-in backends that execute scalar ticks: a *pinned* ``par_vec > 1``
 #: there would silently report (and price) a vector width the executable
 #: never realizes, so ``plan()`` rejects it.  Custom registered backends
 #: are unrestricted — they may well wrap the vectorized kernels.
-SCALAR_TICK_BACKENDS = ("engine", "reference", "distributed")
+SCALAR_TICK_BACKENDS = ("engine", "reference")
 
 #: backends compiled by Mosaic for the chip: their geometry is tile-aligned
-#: (``BlockGeometry.align``) so every HBM DMA window starts on a tile
+#: (``BlockGeometry.align``) so every HBM DMA window starts on a tile.
+#: ``distributed`` is, too, when its mesh is made of TPUs (:func:`_aligned`)
 ALIGNED_BACKENDS = ("pallas",)
+
+
+def _aligned(config: RunConfig) -> bool:
+    """Whether the plan's kernels are compiled by Mosaic: the ``pallas``
+    backend, or ``distributed`` on a mesh of TPUs (elsewhere its kernels
+    run interpreted, on any geometry)."""
+    if config.backend == "distributed" and config.mesh is not None:
+        device = config.mesh.devices.flat[0]
+        return getattr(device, "platform", None) == "tpu"
+    return config.backend in ALIGNED_BACKENDS
 
 
 def _tiles(problem: StencilProblem, config: RunConfig):
     """``(stream_tile, align)`` the backend's kernels need (``(1, ())`` for
     backends that run any geometry)."""
-    if config.backend not in ALIGNED_BACKENDS:
+    if not _aligned(config):
         return 1, ()
     return tpu_tiles(problem.ndim, config.resolved_cell_bytes(problem.dtype))
 
@@ -84,12 +96,12 @@ def _candidate_shortlist(problem: StencilProblem, config: RunConfig,
     cands = perf_model.autotune(
         problem.stencil, problem.shape, config.iters_hint, device,
         config.resolved_cell_bytes(problem.dtype),
-        config.par_time_max, n_chips, chip_grid,
+        _par_time_max(problem, config), n_chips, chip_grid,
         par_time=config.par_time,
         bsize=config.normalized_bsize(problem.ndim),
         par_vec=par_vec, top_k=top_k,
         bc=problem.structural_bc,
-        aligned=config.backend in ALIGNED_BACKENDS)
+        aligned=_aligned(config))
     if not cands:
         raise ValueError(
             f"no VMEM-feasible (bsize, par_time, par_vec) for "
@@ -150,7 +162,7 @@ def _resolve_measured(problem: StencilProblem, config: RunConfig,
                     config.resolved_cell_bytes(problem.dtype),
                     n_chips, chip_grid,
                     bc=problem.structural_bc, par_vec=par_vec,
-                    aligned=config.backend in ALIGNED_BACKENDS)
+                    aligned=_aligned(config))
             except (KeyError, TypeError, ValueError):
                 entry = None
             else:
@@ -175,14 +187,40 @@ def _resolve_measured(problem: StencilProblem, config: RunConfig,
     return best.geom.par_time, best.geom.bsize, best.geom.par_vec, tuned, False
 
 
-def _validate_distributed(problem: StencilProblem, config: RunConfig) -> None:
-    """Fail at plan time (not first ``run()``) when the mesh cannot shard the
-    grid evenly — ``predict`` ceil-divides, so only this check catches it."""
+def _shard_par_time_max(problem: StencilProblem,
+                        config: RunConfig) -> Optional[int]:
+    """The deepest ``par_time`` whose halo (radius x ``par_time``) every
+    shard of a sharded axis can feed from its own cells; ``None`` off-mesh.
+    Raises at plan time (not first ``run()``) when the mesh cannot shard
+    the grid evenly — ``predict`` ceil-divides, so only this catches it."""
     if config.backend != "distributed" or config.mesh is None:
-        return
+        return None
     from repro.core.distributed import shard_extents
-    shard_extents(problem.shape, resolve_axis_map(problem, config),
-                  config.mesh)
+    axis_map = resolve_axis_map(problem, config)
+    local = shard_extents(problem.shape, axis_map, config.mesh)
+    sizes = dict(zip(config.mesh.axis_names, config.mesh.devices.shape))
+    rad = problem.stencil.radius
+    caps = [ld // rad for ld, names in zip(local, axis_map)
+            if names and rad and math.prod(sizes[a] for a in names) > 1]
+    return min(caps) if caps else None
+
+
+def _par_time_max(problem: StencilProblem, config: RunConfig) -> int:
+    cap = _shard_par_time_max(problem, config)
+    return config.par_time_max if cap is None else min(cap,
+                                                       config.par_time_max)
+
+
+def _validate_distributed(problem: StencilProblem, config: RunConfig) -> None:
+    """Fail at plan time when the mesh cannot shard the grid evenly, or a
+    pinned ``par_time`` asks for a halo wider than a shard."""
+    cap = _shard_par_time_max(problem, config)
+    if cap is not None and (config.par_time or 0) > cap:
+        raise ValueError(
+            f"par_time={config.par_time} needs a halo of "
+            f"{problem.stencil.radius * config.par_time} cells; the "
+            f"narrowest shard of this mesh has {cap * problem.stencil.radius}"
+            f" — pin par_time <= {cap}")
 
 
 @tracing.span("stencil.plan")
@@ -225,9 +263,11 @@ def plan(problem: StencilProblem, config: Optional[RunConfig] = None,
                 f"par_vec={par_vec} is not a multiple of {stream_tile}: the "
                 f"{config.backend!r} kernels move (par_vec, ...) slabs that "
                 f"must fill whole {problem.dtype} tiles")
-        geom = BlockGeometry(problem.ndim, problem.shape,
-                             problem.stencil.radius, par_time, tuple(bsize),
-                             par_vec, align)
+        # on a mesh, the block one chip's kernel streams: its shard
+        # extended by the halo on each sharded side
+        geom = BlockGeometry(problem.ndim, perf_model.block_dims(
+            problem.stencil, problem.shape, par_time, n_chips, chip_grid),
+            problem.stencil.radius, par_time, tuple(bsize), par_vec, align)
     except ValueError:
         if config.backend != "reference":
             raise

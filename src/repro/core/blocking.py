@@ -308,10 +308,14 @@ def bsize_feasible(rad: int, par_time: int, bsize: Sequence[int],
                for b, a in zip(bsize, align))
 
 
-def _aligned_extents(dim: int, tile: int, cap: int) -> list:
+def _aligned_extents(dim: int, tile: int, cap: int,
+                     splits: bool = False) -> list:
     """Compute extents for one aligned blocked dim: power-of-two tile
     multiples below the grid extent, then the extent rounded up to the
-    tile (one block spanning the dim), all at most ``cap``."""
+    tile (one block spanning the dim), all at most ``cap``.  ``splits``
+    adds the extents that cut the dim into 2..16 near-equal blocks (a
+    mesh shard's halo-extended block, just past a power of two, would
+    otherwise need one nearly empty block more)."""
     full = -(-dim // tile) * tile
     out, c = [], tile
     while c < full and c <= cap:
@@ -319,12 +323,17 @@ def _aligned_extents(dim: int, tile: int, cap: int) -> list:
         c *= 2
     if full <= cap:
         out.append(full)
+    if splits:
+        out = sorted(set(out) | {c for c in (-(-dim // (n * tile)) * tile
+                                             for n in range(2, 17))
+                                 if c <= cap})
     return out or [tile]
 
 
 def choose_bsize_candidates(ndim: int, dims: Sequence[int], rad: int = 1,
                             par_time: int | None = None,
-                            align: Sequence[int] = ()) -> list:
+                            align: Sequence[int] = (),
+                            splits: bool = False) -> list:
     """Power-of-two block extents, lane-aligned (paper §5.3 restrictions).
 
     When ``par_time`` is given, candidates infeasible for that temporal
@@ -334,7 +343,9 @@ def choose_bsize_candidates(ndim: int, dims: Sequence[int], rad: int = 1,
     With ``align`` (the compiled kernels' tiles, :func:`tpu_tiles`) the
     sweep is over tile-multiple *compute* extents instead, each block
     ``csize + 2*pad`` wide, so every candidate's DMA windows start on a
-    tile; this needs ``par_time``."""
+    tile; this needs ``par_time``.  ``splits`` adds the compute extents
+    that cut each aligned dim into near-equal blocks
+    (:func:`_aligned_extents`)."""
     out = []
     if ndim == 1:
         return [()]                  # stream-only: nothing to block
@@ -342,7 +353,7 @@ def choose_bsize_candidates(ndim: int, dims: Sequence[int], rad: int = 1,
         halo = rad * (par_time or 1)
         caps = (1 << 14,) if ndim == 2 else (512, 1 << 12)
         per_dim = [[c + 2 * (-(-halo // a) * a)
-                    for c in _aligned_extents(d, a, cap)]
+                    for c in _aligned_extents(d, a, cap, splits)]
                    for d, a, cap in zip(dims[1:], align, caps)]
         return [tuple(bs) for bs in itertools.product(*per_dim)]
     if ndim == 2:

@@ -134,6 +134,20 @@ class Prediction:
                 f"{self.gcells_s / 1e9:.2f} GCell/s, red={self.geom.redundancy:.2f})")
 
 
+def block_dims(stencil: Stencil, dims, par_time: int, n_chips: int,
+                chip_grid) -> tuple:
+    """The extents one chip's kernel streams: the grid on one chip, else a
+    shard extended by the ``rad * par_time`` halo on each sharded side
+    (core/distributed.py)."""
+    if n_chips <= 1:
+        return tuple(dims)
+    cg = (tuple(chip_grid) if chip_grid
+          else (n_chips,) + (1,) * (len(dims) - 1))
+    halo = stencil.radius * par_time
+    return tuple(math.ceil(d / c) + (2 * halo if c > 1 else 0)
+                 for d, c in zip(dims, cg))
+
+
 def predict(stencil: Stencil, dims: Sequence[int], iters: int,
             bsize, par_time: int, device: Device = TPU_V5E,
             cell_bytes: int = 4, n_chips: int = 1,
@@ -170,7 +184,8 @@ def predict(stencil: Stencil, dims: Sequence[int], iters: int,
     both read and traversed).  Periodic *sharded* axes exchange on a full
     wrap-around ring: per-chip halo bytes are unchanged (interior shards
     already sent both strips, which is what ``t_halo`` prices as the
-    critical path), so only the memory/compute terms move.
+    critical path), and a sharded periodic stream axis needs no stream
+    extension (the ring brings the wrap).
 
     ``aligned`` prices the geometry the compiled kernels run: halos and
     compute extents rounded to the TPU tiles (:func:`tpu_tiles`).
@@ -184,14 +199,18 @@ def predict(stencil: Stencil, dims: Sequence[int], iters: int,
     if n_chips > 1:
         cg = tuple(chip_grid) if chip_grid else (n_chips,) + (1,) * (len(dims) - 1)
         local_dims = tuple(math.ceil(d / c) for d, c in zip(dims, cg))
-    geom = BlockGeometry(len(dims), local_dims, stencil.radius, par_time,
+    # bill the block each chip's kernel streams: its shard extended by the
+    # halo on every sharded side
+    geom = BlockGeometry(len(dims), block_dims(stencil, dims, par_time,
+                                                n_chips, chip_grid),
+                         stencil.radius, par_time,
                          tuple(bsize), par_vec,
                          tpu_tiles(len(dims), cell_bytes)[1] if aligned
                          else ())
     # periodic stream BC: the kernels stream 2*size_halo extra rows/planes
     # per super-step (the materialized wrap) — bill traffic/compute on the
     # extended geometry, report the caller-visible one
-    geom_t = extended_geometry(geom, bc)
+    geom_t = extended_geometry(geom, bc) if cg[0] == 1 else geom
 
     # --- memory term (paper Eq. 3: th_mem saturates at th_max = HBM bw) ----
     step_bytes = superstep_traffic_bytes(geom_t, stencil.num_read,
@@ -306,8 +325,9 @@ def autotune(stencil: Stencil, dims: Sequence[int], iters: int,
                    if bsize_feasible(stencil.radius, pt, bsize, align)
                    else [])
         else:
-            bss = choose_bsize_candidates(len(dims), dims, stencil.radius, pt,
-                                          align)
+            bss = choose_bsize_candidates(
+                len(dims), block_dims(stencil, dims, pt, n_chips, chip_grid),
+                stencil.radius, pt, align, splits=n_chips > 1)
         for bs in bss:
             for pv in pvs:
                 p = predict(stencil, dims, iters, bs, pt, device,

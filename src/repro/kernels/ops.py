@@ -88,7 +88,7 @@ def _slice_blocked(gp: jnp.ndarray, geom: BlockGeometry,
 
 
 def _reclamp_padded(gp: jnp.ndarray, geom: BlockGeometry,
-                    bc=None) -> jnp.ndarray:
+                    bc=None, exchange=None) -> jnp.ndarray:
     """Refresh the halo + out-of-bound columns of a padded grid from its real
     columns, per each axis' BC rule.  Bit-identical to
     ``_pad_blocked(_slice_blocked(gp))``, but keeps the array in the padded
@@ -106,16 +106,24 @@ def _reclamp_padded(gp: jnp.ndarray, geom: BlockGeometry,
     Axes whose pad is zero are skipped outright: there is nothing to write
     and, for the constant BC, a ghost mask there would wrongly treat real
     edge columns as ghost positions (the zero-pad seam case — e.g. a
-    stream-only stencil embedded in a higher-rank grid)."""
+    stream-only stencil embedded in a higher-rank grid).
+
+    On a shard of a mesh, ``exchange(gp, axis, i)`` (``core/distributed.py``)
+    first refreshes grid axis ``i``'s halos from the neighbours, before that
+    axis' own strips, so the strips and the corners follow from them."""
     kinds = boundary.kinds_of(bc, geom.ndim)
     fill = boundary.fill_of(bc)
     ext = _stream_ext(geom, bc)
     axis = gp.ndim - geom.ndim                # the streaming axis
+    if exchange is not None:
+        gp = exchange(gp, axis, 0)
     if ext:
         d = geom.stream_dim
         gp = _refresh_strips(gp, axis, ext, d, d + 2 * ext, "periodic", fill)
     for i, (d, p, h) in enumerate(zip(geom.blocked_dims, geom.padded_dims,
                                       geom.pad)):
+        if exchange is not None:
+            gp = exchange(gp, axis + 1 + i, i + 1)
         if p != d:
             gp = _refresh_strips(gp, axis + 1 + i, h, d, p, kinds[i + 1],
                                  fill)
@@ -174,10 +182,45 @@ def _broadcast_edge(gp: jnp.ndarray, axis: int, at: int,
                             + gp.shape[axis + 1:])
 
 
+def _per_member(kernel, gp: jnp.ndarray, aux_p, rank: int, aux_rank: int):
+    """``kernel(gp, aux_p)``, or over each member of a leading batch axis
+    when ``gp`` has one more axis than the kernel's ``rank`` (an ``aux_p``
+    of more than ``aux_rank`` axes is per member too).  The batch is mapped
+    sequentially (``lax.map``): a ``vmap`` over the manual-DMA kernel
+    mis-addresses its per-block DMAs."""
+    if gp.ndim == rank:
+        return kernel(gp, aux_p)
+    if aux_p is not None and aux_p.ndim > aux_rank:
+        return jax.lax.map(lambda ga: kernel(*ga), (gp, aux_p))
+    return jax.lax.map(lambda g: kernel(g, aux_p), gp)
+
+
+def _fused_loop(kernel, geom: BlockGeometry, gp: jnp.ndarray, iters,
+                aux_p, rank: int, refresh, unpad) -> jnp.ndarray:
+    """``ceil(iters / par_time)`` super-steps of ``kernel(g, steps, aux_p)``
+    over the padded carry ``gp``, ``refresh`` rewriting the carry's padding
+    strips after each, ``unpad`` taking the real cells out at the end."""
+    par_time = geom.par_time
+    n_super = (iters + par_time - 1) // par_time
+
+    def body(s, g):
+        steps = jnp.minimum(par_time, iters - s * par_time)
+        with jax.named_scope("stencil.superstep"):
+            op = _per_member(lambda x, a: kernel(x, steps, a), g, aux_p,
+                             rank, geom.ndim)
+        with jax.named_scope("stencil.halo_refresh"):
+            return refresh(op)
+
+    out = jax.lax.fori_loop(0, n_super, body, gp)
+    with jax.named_scope("stencil.unpad"):
+        return unpad(out)
+
+
 def fused_chain_loop(stages, geom: BlockGeometry, gp: jnp.ndarray,
                      coeffs_packed: jnp.ndarray, iters,
                      aux_p: jnp.ndarray | None, interpret: bool,
-                     block_parallel: bool = False) -> jnp.ndarray:
+                     block_parallel: bool = False, *, refresh=None,
+                     unpad=None) -> jnp.ndarray:
     """The throughput subsystem's fused driver: the whole ``iters`` loop of a
     stage chain over the *pre-padded* grid ``gp``, returning the unpadded
     result.  ``stages`` is the static ``((stencil, bc), ...)`` tuple of the
@@ -189,65 +232,61 @@ def fused_chain_loop(stages, geom: BlockGeometry, gp: jnp.ndarray,
         compiled executable serves every iteration count (no per-``iters``
         re-trace in a serving loop).
       * The carry stays in the padded layout: between super-steps
-        ``_reclamp_padded`` writes only the padding strips (halo, overhang,
-        periodic stream extension), in place in the kernel's output, instead
-        of a slice+re-pad round-trip or any pass over the whole array; and
-        a caller that jits this function with ``donate_argnums`` on ``gp``
-        lets XLA reuse the padded buffer for the loop carry — ``gp`` is an
-        intermediate the backend owns, so donation never invalidates a
-        caller-visible array.
+        ``refresh`` writes only the padding strips, in place in the kernel's
+        output, instead of a slice+re-pad round-trip or any pass over the
+        whole array; and a caller that jits this function with
+        ``donate_argnums`` on ``gp`` lets XLA reuse the padded buffer for
+        the loop carry — ``gp`` is an intermediate the backend owns, so
+        donation never invalidates a caller-visible array.
 
-    Padding, the stream extension and inter-super-step halo refresh use stage
-    0's BC: that is the BC the chain's first entry reads the carry under
-    (periodicity is uniform across stages by construction, and each later
-    entry re-imposes its own BC in-kernel).
+    ``refresh`` and ``unpad`` default to one chip's layout:
+    ``_reclamp_padded`` (halo, overhang and periodic stream extension under
+    stage 0's BC: the BC the chain's first entry reads the carry under —
+    periodicity is uniform across stages by construction, and each later
+    entry re-imposes its own BC in-kernel) and ``_slice_blocked``.  A shard
+    of a mesh passes its own (``core/distributed.py``): the halo exchange
+    with its neighbours plus the strips of its physical edges.  A ``gp``
+    with a leading batch axis runs the kernel per member between two
+    refreshes (:func:`_per_member`).
     """
     bc0 = stages[0][1]
-    par_time = geom.par_time
-    n_super = (iters + par_time - 1) // par_time
+    refresh = refresh or partial(_reclamp_padded, geom=geom, bc=bc0)
+    unpad = unpad or partial(_slice_blocked, geom=geom, bc=bc0)
 
-    def body(s, g):
-        steps = jnp.minimum(par_time, iters - s * par_time)
-        with jax.named_scope("stencil.superstep"):
-            op = superstep_chain(stages, geom, g, coeffs_packed, steps, aux_p,
-                                 interpret=interpret,
-                                 block_parallel=block_parallel)
-        with jax.named_scope("stencil.halo_refresh"):
-            return _reclamp_padded(op, geom, bc0)
+    def kernel(g, steps, a):
+        return superstep_chain(stages, geom, g, coeffs_packed, steps, a,
+                               interpret=interpret,
+                               block_parallel=block_parallel)
 
-    out = jax.lax.fori_loop(0, n_super, body, gp)
-    with jax.named_scope("stencil.unpad"):
-        return _slice_blocked(out, geom, bc0)
+    return _fused_loop(kernel, geom, gp, iters, aux_p, geom.ndim, refresh,
+                       unpad)
 
 
 def fused_dag_loop(dag, geom: BlockGeometry, gp: jnp.ndarray,
                    coeffs_packed: jnp.ndarray, iters,
                    aux_p: jnp.ndarray | None, interpret: bool,
-                   block_parallel: bool = False) -> jnp.ndarray:
+                   block_parallel: bool = False, *, refresh=None,
+                   unpad=None) -> jnp.ndarray:
     """DAG analogue of :func:`fused_chain_loop`: the whole ``iters`` loop of
     a stage DAG (:class:`repro.programs.DagSpec`) over the *pre-padded*
     state ``gp`` (``(ns, *padded)`` single-field, ``(F, ns, *padded)``
     multi-field — every field padded identically), returning the unpadded
-    result.  The carry stays padded; the padding strips of all fields are
-    rewritten in place by one ``_reclamp_padded`` per super-step under stage
-    0's BC (periodicity is uniform by construction; each entry re-imposes
-    its own BC in-kernel)."""
+    result.  The carry stays padded; by default the padding strips of all
+    fields are rewritten in place by one ``_reclamp_padded`` per super-step
+    under stage 0's BC (periodicity is uniform by construction; each entry
+    re-imposes its own BC in-kernel); ``refresh``, ``unpad`` and a leading
+    batch axis as in :func:`fused_chain_loop`."""
     bc0 = dag.stages[0][1]
-    par_time = geom.par_time
-    n_super = (iters + par_time - 1) // par_time
+    refresh = refresh or partial(_reclamp_padded, geom=geom, bc=bc0)
+    unpad = unpad or partial(_slice_blocked, geom=geom, bc=bc0)
 
-    def body(s, g):
-        steps = jnp.minimum(par_time, iters - s * par_time)
-        with jax.named_scope("stencil.superstep"):
-            op = superstep_dag(dag, geom, g, coeffs_packed, steps, aux_p,
-                               interpret=interpret,
-                               block_parallel=block_parallel)
-        with jax.named_scope("stencil.halo_refresh"):
-            return _reclamp_padded(op, geom, bc0)
+    def kernel(g, steps, a):
+        return superstep_dag(dag, geom, g, coeffs_packed, steps, a,
+                             interpret=interpret,
+                             block_parallel=block_parallel)
 
-    out = jax.lax.fori_loop(0, n_super, body, gp)
-    with jax.named_scope("stencil.unpad"):
-        return _slice_blocked(out, geom, bc0)
+    rank = geom.ndim + (dag.n_fields > 1)
+    return _fused_loop(kernel, geom, gp, iters, aux_p, rank, refresh, unpad)
 
 
 def fused_superstep_loop(stencil: Stencil, geom: BlockGeometry,

@@ -164,3 +164,45 @@ def test_super_step_phases_carry_their_scopes(one_chip, name, shape):
     assert all(refresh[n] == carry for n in strips)
     assert all(refresh[n] != carry for n in refresh if n not in strips)
     assert any("/stencil.unpad/" in o for o in names.values())
+
+
+def test_mesh_cell_compiles_for_a_2x2_v5e(one_chip, topo):
+    """The four-chip Diffusion 2D cell (49152², rows over ``x``, columns
+    over ``y``) compiled for a described 2x2 v5e: every shard runs the
+    kernel ``superstep_chain`` under ``stencil.superstep``; each super-step
+    sends one strip per direction per sharded axis, every
+    collective-permute under ``stencil.halo_exchange``; no gather, and no
+    concatenate of a whole shard, anywhere in the program; and one chip
+    holds it within its 16 GB."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+    shape = (49152, 49152)
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("x", "y"))
+    p = plan(StencilProblem("diffusion2d", shape),
+             RunConfig(backend="distributed", autotune="model", mesh=mesh,
+                       axis_map=(("x",), ("y",))))
+    assert p.geometry.align and p.n_chips == 4
+    spec = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=NamedSharding(
+        mesh, PartitionSpec("x", "y")))
+    compiled = p.lower(spec).compile()
+    hlo = compiled.as_text()
+    names = _op_names(hlo)
+    kernels = [n for n in names
+               if re.fullmatch(r"superstep_chain(\.\d+)?", n)]
+    assert len(kernels) == 1
+    assert "stencil.superstep/" in names[kernels[0]]
+    permutes = [n for n in names if n.startswith("collective-permute")]
+    assert permutes
+    assert all("/stencil.halo_exchange/" in names[n] for n in permutes)
+    body = re.search(r"while\([^)]*\), condition=%\S+, body=%([\w.-]+)",
+                     hlo).group(1)
+    body = re.search(rf"^%{re.escape(body)} [^\n]*\{{\n(.*?)\n\}}", hlo,
+                     re.M | re.S).group(1)
+    assert len(re.findall(r" collective-permute-start\(", body)) == 4
+    assert not re.search(r"\bgather\(", hlo)
+    shard = (shape[0] // 2) * (shape[1] // 2)
+    for dims in re.findall(r"= f32\[([\d,]+)\]\S* concatenate\(", hlo):
+        assert np.prod([int(d) for d in dims.split(",")]) < shard
+    mem = compiled.memory_analysis()
+    per_chip = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+                + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert per_chip < 16e9, per_chip
