@@ -96,7 +96,7 @@ def _stream_shifts(kind: str, dom: int, ds: int) -> tuple:
 
 
 def _dag_kernel(*refs, plan, lay, geom: BlockGeometry, ns: int, dom: int,
-                sdtype=jnp.float32):
+                sdtype=jnp.float32, dst: bool = False):
     # mixed precision (repro.core.precision): every VMEM buffer — windows,
     # DMA slabs — holds the STORAGE dtype ``sdtype``; stage arithmetic runs
     # in f32.  For bf16 that means: widen the concatenated window read (and
@@ -135,6 +135,7 @@ def _dag_kernel(*refs, plan, lay, geom: BlockGeometry, ns: int, dom: int,
     aux_ref = None
     if has_aux:
         aux_ref, p = refs[p], p + 1
+    p += dst                  # the destination operand: aliased, never read
     out_ref, p = refs[p], p + 1
     win_refs, p = refs[p:p + len(win_ids)], p + len(win_ids)
     win_of = dict(zip(win_ids, win_refs))
@@ -499,7 +500,24 @@ def _scratch_shapes(dag: DagSpec, geom: BlockGeometry, sdtype, lay=None):
 def _superstep_dag_impl(dag: DagSpec, geom: BlockGeometry, gp: jnp.ndarray,
                         coeffs_packed: jnp.ndarray, steps: jnp.ndarray,
                         aux_p: Optional[jnp.ndarray], interpret: bool,
-                        block_parallel: bool) -> jnp.ndarray:
+                        block_parallel: bool,
+                        dst: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+    """The ``pallas_call`` of one super-step; its output is a fresh array of
+    ``gp``'s shape, or ``dst`` itself when one is given.
+
+    ``dst`` (``gp``'s shape and dtype) is an extra ``pl.ANY`` operand aliased
+    to the output (``input_output_aliases``): the kernel writes into it and
+    never reads it, so a super-step loop can alternate between two buffers
+    instead of letting XLA copy a fresh output back into its carry.  Write
+    coverage, the invariant that makes this safe: every block writes every
+    stream row (the periodic stream extension and the ``par_vec`` tail rows
+    included) of its compute columns ``[pad + i*csize, pad + (i+1)*csize)``,
+    so the compute columns and the overhang are rewritten every super-step.
+    What stays as ``dst`` held it — the leading ``pad`` and the trailing
+    columns past ``pad + bnum*csize`` of each blocked axis — lies inside the
+    padding strips the loop's halo refresh rewrites (from the real cells,
+    ``kernels/ops._reclamp_padded``) before the next kernel reads the buffer,
+    so values from two super-steps back never reach a result."""
     nb = geom.ndim - 1
     V = geom.par_vec
     F = dag.n_fields
@@ -523,11 +541,11 @@ def _superstep_dag_impl(dag: DagSpec, geom: BlockGeometry, gp: jnp.ndarray,
     # working set); the kernel widens reads to f32 for the stage arithmetic
     sdtype = gp.dtype
     kernel = functools.partial(_dag_kernel, plan=plan, lay=lay, geom=geom,
-                               ns=ns, dom=dom, sdtype=sdtype)
+                               ns=ns, dom=dom, sdtype=sdtype,
+                               dst=dst is not None)
     scratch = _scratch_shapes(dag, geom, sdtype, lay)
-    n_hbm_in = 2 if has_aux else 1
     operands = (coeffs_packed.reshape(1, -1), gp) + (
-        (aux_p,) if has_aux else ())
+        (aux_p,) if has_aux else ()) + ((dst,) if dst is not None else ())
     steps_arr = jnp.asarray(steps, jnp.int32).reshape(1, 1)
     grid = geom.bnum if nb else (1,)
     return pl.pallas_call(
@@ -535,10 +553,12 @@ def _superstep_dag_impl(dag: DagSpec, geom: BlockGeometry, gp: jnp.ndarray,
         grid=grid,
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
                   pl.BlockSpec(memory_space=pltpu.VMEM)]
-        + [pl.BlockSpec(memory_space=pl.ANY)] * n_hbm_in,
+        + [pl.BlockSpec(memory_space=pl.ANY)] * (len(operands) - 1),
         out_specs=pl.BlockSpec(memory_space=pl.ANY),
         scratch_shapes=scratch,
         out_shape=jax.ShapeDtypeStruct(gp.shape, sdtype),
+        # the destination is the last operand, after ``steps_arr``
+        input_output_aliases={len(operands): 0} if dst is not None else {},
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=(
@@ -554,7 +574,8 @@ def superstep_dag(dag: DagSpec, geom: BlockGeometry, gp: jnp.ndarray,
                   coeffs_packed: jnp.ndarray, steps: jnp.ndarray,
                   aux_p: Optional[jnp.ndarray] = None,
                   interpret: bool = True,
-                  block_parallel: bool = False) -> jnp.ndarray:
+                  block_parallel: bool = False,
+                  dst: Optional[jnp.ndarray] = None) -> jnp.ndarray:
     """One super-step (<= ``par_time`` fused program iterations) of a stage
     DAG over the padded state ``gp`` (``(ns, *padded)`` for single-field
     programs, ``(F, ns, *padded)`` for multi-field), through the unrolled
@@ -569,9 +590,12 @@ def superstep_dag(dag: DagSpec, geom: BlockGeometry, gp: jnp.ndarray,
     ``block_parallel`` opts the kernel grid into Megacore ("parallel"
     dimension semantics): blocks are independent by construction, so the
     result is bit-identical to the sequential grid.
+
+    ``dst``, a buffer of ``gp``'s shape, receives the output in place
+    (:func:`_superstep_dag_impl` says which of its cells are rewritten).
     """
     return _superstep_dag_impl(dag, geom, gp, coeffs_packed, steps, aux_p,
-                               interpret, block_parallel)
+                               interpret, block_parallel, dst)
 
 
 @functools.partial(jax.jit,
@@ -581,7 +605,8 @@ def superstep_chain(stages, geom: BlockGeometry, gp: jnp.ndarray,
                     coeffs_packed: jnp.ndarray, steps: jnp.ndarray,
                     aux_p: Optional[jnp.ndarray] = None,
                     interpret: bool = True,
-                    block_parallel: bool = False) -> jnp.ndarray:
+                    block_parallel: bool = False,
+                    dst: Optional[jnp.ndarray] = None) -> jnp.ndarray:
     """One super-step through the ``len(stages) * par_time``-entry PE chain.
 
     ``stages``: static tuple of ``(stencil, bc)`` per program stage (S=1
@@ -590,6 +615,8 @@ def superstep_chain(stages, geom: BlockGeometry, gp: jnp.ndarray,
     :func:`superstep_dag`: linear chains unroll to the identical entry list
     (fused per-entry PE-forwarding selects, same windows, same scratch), so
     this builds the same kernel PR 6 shipped, bit for bit.
+
+    ``dst`` as in :func:`superstep_dag`.
     """
     return _superstep_dag_impl(chain_dag(stages), geom, gp, coeffs_packed,
-                               steps, aux_p, interpret, block_parallel)
+                               steps, aux_p, interpret, block_parallel, dst)

@@ -182,14 +182,11 @@ def _broadcast_edge(gp: jnp.ndarray, axis: int, at: int,
                             + gp.shape[axis + 1:])
 
 
-def _per_member(kernel, gp: jnp.ndarray, aux_p, rank: int, aux_rank: int):
-    """``kernel(gp, aux_p)``, or over each member of a leading batch axis
-    when ``gp`` has one more axis than the kernel's ``rank`` (an ``aux_p``
-    of more than ``aux_rank`` axes is per member too).  The batch is mapped
-    sequentially (``lax.map``): a ``vmap`` over the manual-DMA kernel
-    mis-addresses its per-block DMAs."""
-    if gp.ndim == rank:
-        return kernel(gp, aux_p)
+def _per_member(kernel, gp: jnp.ndarray, aux_p, aux_rank: int):
+    """``kernel(g, aux_p)`` over each member ``g`` of ``gp``'s leading batch
+    axis (an ``aux_p`` of more than ``aux_rank`` axes is per member too).
+    The batch is mapped sequentially (``lax.map``): a ``vmap`` over the
+    manual-DMA kernel mis-addresses its per-block DMAs."""
     if aux_p is not None and aux_p.ndim > aux_rank:
         return jax.lax.map(lambda ga: kernel(*ga), (gp, aux_p))
     return jax.lax.map(lambda g: kernel(g, aux_p), gp)
@@ -197,23 +194,62 @@ def _per_member(kernel, gp: jnp.ndarray, aux_p, rank: int, aux_rank: int):
 
 def _fused_loop(kernel, geom: BlockGeometry, gp: jnp.ndarray, iters,
                 aux_p, rank: int, refresh, unpad) -> jnp.ndarray:
-    """``ceil(iters / par_time)`` super-steps of ``kernel(g, steps, aux_p)``
-    over the padded carry ``gp``, ``refresh`` rewriting the carry's padding
-    strips after each, ``unpad`` taking the real cells out at the end."""
+    """``ceil(iters / par_time)`` super-steps of ``kernel(g, steps, aux_p,
+    dst)`` over the padded state ``gp``, ``refresh`` rewriting the padding
+    strips after each, ``unpad`` taking the real cells out at the end.
+
+    The carry is two padded buffers the kernel alternates between: each
+    super-step reads one and writes into the other (``dst``, aliased to the
+    kernel's output), and ``refresh`` then writes that one's strips in
+    place.  A kernel cannot write into the buffer it reads (its blocks read
+    their neighbours' old cells as halo), and with one buffer XLA copies the
+    kernel's fresh output back into the carry before every kernel.  The
+    kernel rewrites every cell but the padding strips, which ``refresh``
+    rewrites (``kernels/builder._superstep_dag_impl``), so nothing a buffer
+    held two super-steps back survives into a result.
+
+    The carry's positions stay fixed (a swap would bring the copy back), so
+    super-steps run in pairs, A -> B then B -> A.  The first super-step's own
+    output, a fresh array, is the second buffer; ``gp`` is the first; a
+    trailing odd super-step and ``iters == 0`` are branches of a
+    ``lax.cond``, so ``iters`` stays traced.  A ``gp`` with a leading batch
+    axis (more than ``rank`` axes) keeps one buffer: the kernel runs per
+    member (:func:`_per_member`), and ``lax.map`` has no buffer for it to
+    write into."""
     par_time = geom.par_time
     n_super = (iters + par_time - 1) // par_time
 
-    def body(s, g):
+    def step(s, src, dst=None):
         steps = jnp.minimum(par_time, iters - s * par_time)
         with jax.named_scope("stencil.superstep"):
-            op = _per_member(lambda x, a: kernel(x, steps, a), g, aux_p,
-                             rank, geom.ndim)
+            if src.ndim > rank:
+                out = _per_member(lambda x, a: kernel(x, steps, a), src,
+                                  aux_p, geom.ndim)
+            else:
+                out = kernel(src, steps, aux_p, dst)
         with jax.named_scope("stencil.halo_refresh"):
-            return refresh(op)
+            return refresh(out)
 
-    out = jax.lax.fori_loop(0, n_super, body, gp)
-    with jax.named_scope("stencil.unpad"):
-        return unpad(out)
+    def unpadded(g):
+        with jax.named_scope("stencil.unpad"):
+            return unpad(g)
+
+    if gp.ndim > rank:
+        return unpadded(jax.lax.fori_loop(0, n_super, step, gp))
+
+    def pair(k, ab):
+        a, b = ab
+        a = step(2 * k + 1, b, a)
+        return a, step(2 * k + 2, a, b)
+
+    def run():
+        b = step(0, gp)
+        a, b = jax.lax.fori_loop(0, (n_super - 1) // 2, pair, (gp, b))
+        return jax.lax.cond(n_super % 2 == 0,
+                            lambda: unpadded(step(n_super - 1, b, a)),
+                            lambda: unpadded(b))
+
+    return jax.lax.cond(n_super > 0, run, lambda: unpadded(gp))
 
 
 def fused_chain_loop(stages, geom: BlockGeometry, gp: jnp.ndarray,
@@ -231,13 +267,15 @@ def fused_chain_loop(stages, geom: BlockGeometry, gp: jnp.ndarray,
         computed in-trace and the loop lowers to a dynamic ``while``, so one
         compiled executable serves every iteration count (no per-``iters``
         re-trace in a serving loop).
-      * The carry stays in the padded layout: between super-steps
-        ``refresh`` writes only the padding strips, in place in the kernel's
-        output, instead of a slice+re-pad round-trip or any pass over the
-        whole array; and a caller that jits this function with
-        ``donate_argnums`` on ``gp`` lets XLA reuse the padded buffer for
-        the loop carry — ``gp`` is an intermediate the backend owns, so
-        donation never invalidates a caller-visible array.
+      * The carry stays in the padded layout, in two buffers the kernel
+        alternates between (:func:`_fused_loop`): each kernel writes into
+        the buffer the previous one read, and ``refresh`` then writes only
+        that buffer's padding strips, in place, instead of a slice+re-pad
+        round-trip or any pass over the whole array.  ``gp`` is the first
+        buffer, so a caller that jits this function with ``donate_argnums``
+        on ``gp`` offers XLA the padded input for it — ``gp`` is an
+        intermediate the backend owns, so donation never invalidates a
+        caller-visible array.
 
     ``refresh`` and ``unpad`` default to one chip's layout:
     ``_reclamp_padded`` (halo, overhang and periodic stream extension under
@@ -253,10 +291,10 @@ def fused_chain_loop(stages, geom: BlockGeometry, gp: jnp.ndarray,
     refresh = refresh or partial(_reclamp_padded, geom=geom, bc=bc0)
     unpad = unpad or partial(_slice_blocked, geom=geom, bc=bc0)
 
-    def kernel(g, steps, a):
+    def kernel(g, steps, a, dst=None):
         return superstep_chain(stages, geom, g, coeffs_packed, steps, a,
                                interpret=interpret,
-                               block_parallel=block_parallel)
+                               block_parallel=block_parallel, dst=dst)
 
     return _fused_loop(kernel, geom, gp, iters, aux_p, geom.ndim, refresh,
                        unpad)
@@ -271,7 +309,9 @@ def fused_dag_loop(dag, geom: BlockGeometry, gp: jnp.ndarray,
     a stage DAG (:class:`repro.programs.DagSpec`) over the *pre-padded*
     state ``gp`` (``(ns, *padded)`` single-field, ``(F, ns, *padded)``
     multi-field — every field padded identically), returning the unpadded
-    result.  The carry stays padded; by default the padding strips of all
+    result.  The carry stays padded, in two buffers the kernel alternates
+    between (:func:`_fused_loop`; every field of the buffer it writes is
+    rewritten but the padding strips); by default the padding strips of all
     fields are rewritten in place by one ``_reclamp_padded`` per super-step
     under stage 0's BC (periodicity is uniform by construction; each entry
     re-imposes its own BC in-kernel); ``refresh``, ``unpad`` and a leading
@@ -280,10 +320,10 @@ def fused_dag_loop(dag, geom: BlockGeometry, gp: jnp.ndarray,
     refresh = refresh or partial(_reclamp_padded, geom=geom, bc=bc0)
     unpad = unpad or partial(_slice_blocked, geom=geom, bc=bc0)
 
-    def kernel(g, steps, a):
+    def kernel(g, steps, a, dst=None):
         return superstep_dag(dag, geom, g, coeffs_packed, steps, a,
                              interpret=interpret,
-                             block_parallel=block_parallel)
+                             block_parallel=block_parallel, dst=dst)
 
     rank = geom.ndim + (dag.n_fields > 1)
     return _fused_loop(kernel, geom, gp, iters, aux_p, rank, refresh, unpad)

@@ -66,6 +66,10 @@ CASES = {
                           (("a",), ("b",)), 4, 64, 8, 7, None),
     "2x2-diffusion3d": ("diffusion3d", (8, 24, 48), "clamp", (2, 2),
                         (None, ("a",), ("b",)), 2, (12, 16), 1, 5, None),
+    # five super-steps: the loop's first, two pairs of carry buffers, no
+    # trailing odd one
+    "2x2-five-supersteps": ("diffusion2d", (64, 256), ("reflect", "clamp"),
+                            (2, 2), (("a",), ("b",)), 4, 64, 8, 20, None),
 }
 
 
