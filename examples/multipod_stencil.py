@@ -4,7 +4,7 @@ future work, implemented).
 Forces 8 host-platform devices, builds a (2, 2, 2) pod×data×model mesh, and
 runs a Diffusion/Hotspot grid through ``plan()`` with the ``distributed``
 backend — the mesh is just config.  Each shard runs the combined
-spatial+temporal blocked engine with ``rad*par_time``-wide halo exchange
+spatial+temporal blocked kernel with ``rad*par_time``-wide halo exchange
 (ppermute) once per super-step — ``par_time``× fewer exchanges than
 step-by-step halo exchange.  Verifies bit-level agreement with the
 single-device oracle.
@@ -59,14 +59,7 @@ def main():
     assert err < 1e-4
 
     # show the halo-exchange collectives in the compiled HLO
-    from repro.core.distributed import build_distributed_fn
-    fn = build_distributed_fn(HOTSPOT2D, DIMS, ITERS, PAR_TIME, BSIZE,
-                              mesh, axis_map)
-    hlo = fn.lower(
-        jax.ShapeDtypeStruct(DIMS, jnp.float32),
-        jax.ShapeDtypeStruct(DIMS, jnp.float32),
-        {k: jax.ShapeDtypeStruct((), jnp.float32) for k in coeffs},
-    ).compile().as_text()
+    hlo = dist.lower(grid, ITERS, coeffs, aux=aux).compile().as_text()
     n_perm = hlo.count("collective-permute(") + hlo.count(
         "collective-permute-start(")
     print(f"compiled HLO contains {n_perm} collective-permute site(s) "

@@ -70,8 +70,8 @@ def main():
     print(f"  measured ({args.backend}, this host): {dt:.3f} s = "
           f"{gcells:.3f} GCell/s, {gflops:.2f} GFLOP/s, {gbytes:.2f} GB/s")
     print(f"  checksum: {float(jnp.sum(out)):.6e}")
-    print("  (TPU-projected numbers come from the perf model; see "
-          "benchmarks/table4_stencil.py for the model-accuracy table.)")
+    print("  (TPU-projected numbers come from the perf model; "
+          "autotune='measure' times each candidate against it.)")
 
 
 if __name__ == "__main__":

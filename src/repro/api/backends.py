@@ -8,6 +8,10 @@ blocked computation.  It is registered as a factory::
             geom: BlockGeometry | None) -> ExecuteFn | BackendProgram
     ExecuteFn(grid, coeffs, iters, aux) -> grid
 
+``coeffs`` is one coefficient dict per stage of ``problem.exec_dag`` (a
+tuple of one for a single stencil); every built-in runs the problem as
+that stage DAG.
+
 ``plan()`` resolves the name through the registry, so adding a backend (GPU
 Pallas, batched ensembles, ...) is one ``register_backend`` call — no
 if/elif dispatch chain to edit.  A factory may return a bare ``ExecuteFn``
@@ -17,9 +21,9 @@ The built-ins registered below:
 
   ``reference``         unblocked oracle (kernels/ref.py) — ground truth
   ``engine``            pure-JAX blocked engine (core/engine.py)
-  ``pallas``            Pallas kernels compiled for TPU (kernels/stencil*.py)
-  ``pallas_interpret``  same kernels, interpret mode (CPU-correctness)
-  ``distributed``       the pallas kernels on every shard of
+  ``pallas``            Pallas kernel compiled for TPU (kernels/builder.py)
+  ``pallas_interpret``  same kernel, interpret mode (CPU-correctness)
+  ``distributed``       the pallas kernel on every shard of
                         ``config.mesh`` (core/distributed.py), halo strips
                         exchanged once per super-step; compiled on TPU
                         meshes, interpreted elsewhere
@@ -337,64 +341,25 @@ def _vmapped_program(kind: str, problem, config, key_geom,
     return BackendProgram(execute, execute_batch)
 
 
-def _dag_coeffs(coeffs):
-    """Normalize the plan's coefficient payload for the DAG executors: a
-    single-stage DAG program gets a bare dict from ``_coeff_payload`` (the
-    legacy contract) — the executors always take one dict per stage."""
-    return coeffs if isinstance(coeffs, tuple) else (coeffs,)
-
-
 def _reference_backend(problem, config, geom):
-    if problem.is_dag:
-        from repro.kernels.ref import oracle_dag_run
-        dag = problem.exec_dag
+    from repro.kernels.ref import oracle_dag_run
+    dag = problem.exec_dag
 
-        def body(grid, coeffs, iters, aux):
-            _note_trace("reference")
-            return oracle_dag_run(dag, grid, _dag_coeffs(coeffs), iters, aux)
-    elif problem.n_stages > 1:
-        from repro.kernels.ref import oracle_program_run
-        stages = problem.exec_stages
-
-        def body(grid, coeffs, iters, aux):
-            _note_trace("reference")
-            return oracle_program_run(stages, grid, coeffs, iters, aux)
-    else:
-        from repro.kernels.ref import oracle_run
-        st, bc = problem.exec_stages[0]
-
-        def body(grid, coeffs, iters, aux):
-            _note_trace("reference")
-            return oracle_run(st, grid, coeffs, iters, aux, bc=bc)
+    def body(grid, coeffs, iters, aux):
+        _note_trace("reference")
+        return oracle_dag_run(dag, grid, coeffs, iters, aux)
 
     # the oracle ignores blocking: key by problem only, not geometry
     return _vmapped_program("reference", problem, config, None, body)
 
 
 def _engine_backend(problem, config, geom):
-    if problem.is_dag:
-        from repro.core.engine import superstep_loop_dag
-        dag = problem.exec_dag
+    from repro.core.engine import superstep_loop
+    dag = problem.exec_dag
 
-        def body(grid, coeffs, iters, aux):
-            _note_trace("engine")
-            return superstep_loop_dag(dag, geom, grid, _dag_coeffs(coeffs),
-                                      iters, aux)
-    elif problem.n_stages > 1:
-        from repro.core.engine import superstep_loop_chain
-        stages = problem.exec_stages
-
-        def body(grid, coeffs, iters, aux):
-            _note_trace("engine")
-            return superstep_loop_chain(stages, geom, grid, coeffs, iters,
-                                        aux)
-    else:
-        from repro.core.engine import superstep_loop
-        st, bc = problem.exec_stages[0]
-
-        def body(grid, coeffs, iters, aux):
-            _note_trace("engine")
-            return superstep_loop(st, geom, grid, coeffs, iters, aux, bc=bc)
+    def body(grid, coeffs, iters, aux):
+        _note_trace("engine")
+        return superstep_loop(dag, geom, grid, coeffs, iters, aux)
 
     return _vmapped_program("engine", problem, config, geom, body)
 
@@ -412,10 +377,7 @@ def check_pallas_dtype(problem: StencilProblem) -> None:
 
 def _make_pallas_backend(force_interpret: bool):
     def factory(problem, config, geom):
-        from repro.kernels.ops import (fused_chain_loop, fused_dag_loop,
-                                       fused_superstep_loop, pack_coeffs,
-                                       pack_dag_coeffs, pack_program_coeffs,
-                                       _pad_blocked)
+        from repro.kernels.ops import fused_loop, pack_coeffs, _pad_blocked
         bc = problem.structural_bc   # sizes padding + the stream extension
         interpret = force_interpret
         tag = "pallas_interpret" if interpret else "pallas"
@@ -426,41 +388,13 @@ def _make_pallas_backend(force_interpret: bool):
         mc = config.block_parallel
         extra = ("donate", donate, "mc", mc)
 
-        if problem.is_dag:
-            dag = problem.exec_dag
-
-            def run_loop(gp, coeffs_packed, iters, aux_p):
-                return fused_dag_loop(dag, geom, gp, coeffs_packed,
-                                      iters, aux_p, interpret,
-                                      block_parallel=mc)
-
-            def pack(coeffs):
-                return pack_dag_coeffs(dag, _dag_coeffs(coeffs))
-        elif problem.n_stages > 1:
-            stages = problem.exec_stages
-
-            def run_loop(gp, coeffs_packed, iters, aux_p):
-                return fused_chain_loop(stages, geom, gp, coeffs_packed,
-                                        iters, aux_p, interpret,
-                                        block_parallel=mc)
-
-            def pack(coeffs):
-                return pack_program_coeffs(stages, coeffs)
-        else:
-            st, bc1 = problem.exec_stages[0]
-
-            def run_loop(gp, coeffs_packed, iters, aux_p):
-                return fused_superstep_loop(st, geom, gp, coeffs_packed,
-                                            iters, aux_p, interpret, bc1,
-                                            block_parallel=mc)
-
-            def pack(coeffs):
-                return pack_coeffs(st, coeffs)
+        dag = problem.exec_dag
 
         def loop_body(gp, coeffs_packed, iters, aux_p):
             # gp is the backend-owned padded carry: safe to donate
             _note_trace(tag)
-            return run_loop(gp, coeffs_packed, iters, aux_p)
+            return fused_loop(dag, geom, gp, coeffs_packed, iters, aux_p,
+                              interpret, block_parallel=mc)
 
         def build_single():
             return jax.jit(loop_body,
@@ -472,7 +406,7 @@ def _make_pallas_backend(force_interpret: bool):
         def execute(grid, coeffs, iters, aux=None):
             gp = _pad_blocked(grid, geom, bc)
             aux_p = _pad_blocked(aux, geom, bc) if aux is not None else None
-            return single(gp, pack(coeffs),
+            return single(gp, pack_coeffs(dag, coeffs),
                           jnp.asarray(iters, jnp.int32), aux_p)
 
         def execute_batch(grids, coeffs, iters, aux=None):
@@ -485,7 +419,7 @@ def _make_pallas_backend(force_interpret: bool):
             fn = get(key, build_single)
             gps = _pad_blocked(grids, geom, bc)
             aux_p = _pad_blocked(aux, geom, bc) if aux is not None else None
-            return fn(gps, pack(coeffs),
+            return fn(gps, pack_coeffs(dag, coeffs),
                       jnp.asarray(iters, jnp.int32), aux_p)
 
         def lower(grid, coeffs, iters, aux=None, batch=False):
@@ -505,7 +439,7 @@ def _make_pallas_backend(force_interpret: bool):
                          build_single)
             else:
                 fn = single
-            return fn.lower(spec(grid, pad), spec(pack(coeffs)),
+            return fn.lower(spec(grid, pad), spec(pack_coeffs(dag, coeffs)),
                             jax.ShapeDtypeStruct((), jnp.int32,
                                                  sharding=where),
                             None if aux is None else spec(aux, pad))
@@ -540,7 +474,6 @@ def _mesh_sig(mesh) -> tuple:
 
 def _distributed_backend(problem, config, geom):
     from repro.core.distributed import build_distributed_fn
-    st = problem.stencil
     mesh = config.mesh
     axis_map = resolve_axis_map(problem, config)
     get = _program_cache(config.exec_cache)
@@ -549,13 +482,9 @@ def _distributed_backend(problem, config, geom):
 
     def build(batch, aux_batched):
         return build_distributed_fn(
-            st, problem.shape, None, geom.par_time, geom.bsize, mesh,
-            axis_map, batch=batch, aux_batched=aux_batched,
+            problem.exec_dag, problem.shape, None, geom.par_time, geom.bsize,
+            mesh, axis_map, batch=batch, aux_batched=aux_batched,
             trace_hook=lambda: _note_trace("distributed"),
-            bc=problem.structural_bc,
-            stages=(problem.exec_stages
-                    if problem.n_stages > 1 and not problem.is_dag else None),
-            dag=problem.exec_dag if problem.is_dag else None,
             par_vec=geom.par_vec, align=geom.align, block_parallel=mc)
 
     def program(batch, aux):

@@ -237,13 +237,13 @@ def plan(problem: StencilProblem, config: Optional[RunConfig] = None,
     n_chips, chip_grid = _chip_layout(problem, config)
     # The unblocked oracle ignores (bsize, par_time): an unresolvable or
     # invalid schedule degrades a 'reference' plan to geometry-less instead
-    # of failing (legacy stencil_run never validated the oracle's schedule).
+    # of failing.
     geom, cands, from_cache = None, (), False
     try:
         if (config.par_vec is not None and config.par_vec > 1
                 and config.backend in SCALAR_TICK_BACKENDS):
             # inside the try block: the reference oracle degrades schedule
-            # errors to a geometry-less plan (legacy), the others raise
+            # errors to a geometry-less plan, the others raise
             raise ValueError(
                 f"par_vec={config.par_vec} is a Pallas streaming-kernel "
                 f"knob; backend={config.backend!r} executes scalar ticks "
@@ -364,11 +364,11 @@ class StencilPlan:
         return self._execute(grid, coeffs, iters, aux)
 
     def _coeff_payload(self, coeffs):
-        """Resolve run-time coefficients into the backend payload: a plain
-        dict for single-stage problems (the legacy custom-backend contract),
-        a tuple of per-stage dicts for programs.  The no-override payload is
-        resolved once and memoized — it is the common case on the serving
-        hot path, and re-resolving materializes fresh jnp scalars per call."""
+        """Resolve run-time coefficients into the backend payload: one
+        coefficient dict per stage of ``problem.exec_dag`` (a tuple of one
+        for a single stencil).  The no-override payload is resolved once
+        and memoized — it is the common case on the serving hot path, and
+        re-resolving materializes fresh jnp scalars per call."""
         # coefficients are resolved in the ACCUMULATION dtype, not storage:
         # bf16 grids multiply f32 coefficients inside the f32 PE arithmetic
         # (repro.core.precision); for f32 problems the two dtypes coincide
@@ -376,13 +376,10 @@ class StencilPlan:
         if coeffs is None:
             cached = getattr(self, "_default_payload", None)
             if cached is None:
-                resolved = self.problem.resolve_coeffs(None, dtype=dtype)
-                cached = (resolved[0] if self.problem.n_stages == 1
-                          else resolved)
+                cached = self.problem.resolve_coeffs(None, dtype=dtype)
                 object.__setattr__(self, "_default_payload", cached)
             return cached
-        resolved = self.problem.resolve_coeffs(coeffs, dtype=dtype)
-        return resolved[0] if self.problem.n_stages == 1 else resolved
+        return self.problem.resolve_coeffs(coeffs, dtype=dtype)
 
     @tracing.span("stencil.run_batch")
     def run_batch(self, grids, iters: int, coeffs=None, *,

@@ -136,8 +136,9 @@ class StencilProblem:
 
     @property
     def exec_stages(self) -> Tuple[Tuple[Stencil, BoundaryCondition], ...]:
-        """The static ``((stencil, bc), ...)`` tuple the chain executors
-        (engine / kernel builder / oracle) take."""
+        """The static ``((stencil, bc), ...)`` tuple of the stages, in
+        authored order; for a linear program :attr:`exec_dag` is
+        ``chain_dag(exec_stages)``."""
         return tuple((s.stencil, s.boundary) for s in self.stages)
 
     @property
@@ -150,8 +151,10 @@ class StencilProblem:
     @property
     def is_dag(self) -> bool:
         """True when the program is a general DAG (multi-field state, fan-in/
-        fan-out, or non-default wiring) — the backends then route through the
-        topological DAG executors instead of the linear chain fast path."""
+        fan-out, or non-default wiring) rather than a linear chain.  Every
+        backend runs both as :attr:`exec_dag`; the kernel builder unrolls a
+        chain with per-entry PE-forwarding selects and a general DAG with
+        per-field state nodes."""
         return not self._program.is_linear
 
     @property
@@ -171,8 +174,8 @@ class StencilProblem:
     @property
     def exec_dag(self):
         """The resolved program's static :class:`~repro.programs.DagSpec` —
-        what the DAG executors (oracle / engine / kernel builder /
-        distributed) take."""
+        what every executor (oracle / engine / kernel builder /
+        distributed) takes."""
         return self._program.dag
 
     def resolve_coeffs(self, coeffs=None, dtype=None) -> Tuple[dict, ...]:

@@ -97,10 +97,8 @@ def measure_candidates(problem: StencilProblem, config: RunConfig,
                        ) -> Tuple[TunedCandidate, ...]:
     """Time every candidate; return them ranked by amortized per-iteration
     measured time (steady-state fastest first)."""
-    # the exact payload shape the backends take: one dict for single-stage
-    # problems, a tuple of per-stage dicts for programs
-    resolved = problem.resolve_coeffs(dtype=problem.jnp_dtype)
-    coeffs = resolved[0] if problem.n_stages == 1 else resolved
+    # the payload the backends take: one coefficient dict per stage
+    coeffs = problem.resolve_coeffs(dtype=problem.jnp_dtype)
     grid, aux = make_stencil_inputs(jax.random.PRNGKey(0), problem.shape,
                                     problem.needs_aux)
     grid = grid.astype(problem.jnp_dtype)

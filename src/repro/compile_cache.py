@@ -1,7 +1,7 @@
 """Where compiled programs persist across processes.
 
 Compiling the fused super-step for a chip takes seconds to minutes, and
-every entry point (``chip_smoke.py``, ``examples/``, ``benchmarks/``)
+every entry point (``chip_smoke.py``, ``examples/``, ``perfbench/``)
 compiles the same programs again on each start.  JAX's persistent
 compilation cache removes that cost, provided the cache directory does not
 move between runs.

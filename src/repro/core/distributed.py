@@ -3,8 +3,8 @@
 This implements the paper's stated future work (§8: "spatial distribution of
 large stencils on multiple FPGAs") on a TPU mesh: the grid is domain-
 decomposed over mesh axes via ``shard_map``; each device runs the *same*
-fused super-step loop as one chip (``kernels/ops.fused_*_loop``, the Pallas
-streaming kernel ``superstep_chain`` / ``superstep_dag``) on its block;
+fused super-step loop as one chip (``kernels/ops.fused_loop``, the Pallas
+streaming kernel ``superstep_chain``) on its block;
 halos of width ``h = rad * par_time`` are exchanged with ``lax.ppermute``
 **once per super-step** — temporal blocking divides the number of exchanges
 (and thus ICI latency events) by ``par_time``.  That communication
@@ -54,8 +54,7 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.blocking import BlockGeometry
-from repro.core.engine import blocked_superstep
-from repro.core.stencils import Stencil
+from repro.programs import DagSpec, dag_radius
 from repro.resilience.faults import fault_point, register_point
 
 #: fires when a halo exchange is *built* — i.e. at trace time, once per
@@ -129,44 +128,6 @@ def shard_extents(dims, axis_map, mesh: Mesh):
     return tuple(out)
 
 
-def _superstep_stub(stencil: Stencil, geom: BlockGeometry, ext, coeffs,
-                    steps, aux_ext, bounds, bc=None):
-    """Custom-call stand-in for the Pallas streaming kernel (dry-run billing).
-
-    Per-shard (already inside shard_map, so GSPMD sees sharded operands):
-    lowers to one opaque custom-call whose operands+result are the kernel's
-    HBM DMA footprint — grid in, aux in, grid out. The kernel's true DMA
-    schedule adds halo re-reads (+3-8%, `kernels.ops.dma_traffic_bytes`;
-    Table 4's traffic-accuracy column quantifies the gap). Executable on
-    host via the pure-JAX engine, so tests can run this path end-to-end.
-    """
-    import numpy as np
-    nb = len(bounds)
-    ext_arr, keep = ext                  # (extended grid, interior slices)
-
-    def host(ext_h, aux_h, steps_h, bounds_h, *coeff_vals):
-        cf = {k: jnp.asarray(v) for k, v in zip(stencil.coeff_names,
-                                                coeff_vals)}
-        bd = tuple((jnp.asarray(bounds_h[i, 0]), jnp.asarray(bounds_h[i, 1]))
-                   for i in range(nb))
-        out = blocked_superstep(stencil, geom, jnp.asarray(ext_h), cf,
-                                jnp.asarray(steps_h),
-                                jnp.asarray(aux_h) if stencil.has_aux
-                                else None, bounds=bd, bc=bc)
-        return np.asarray(out[keep])
-
-    bounds_arr = jnp.stack([jnp.stack([jnp.asarray(lo, jnp.int32),
-                                       jnp.asarray(hi, jnp.int32)])
-                            for lo, hi in bounds])
-    coeff_vals = [coeffs[k] for k in stencil.coeff_names]
-    aux_in = aux_ext if aux_ext is not None else jnp.zeros((), jnp.float32)
-    out_shape = tuple(len(range(*k.indices(s)))
-                      for k, s in zip(keep, ext_arr.shape))
-    return jax.pure_callback(
-        host, jax.ShapeDtypeStruct(out_shape, ext_arr.dtype), ext_arr,
-        aux_in, steps, bounds_arr, *coeff_vals, vmap_method="sequential")
-
-
 def _real_offsets(axis_map, sharded, periodic, h: int) -> list:
     """Where the shard's real cells start inside its extended block, per
     grid axis: 0 on the low shard of a non-periodic sharded axis (its
@@ -209,15 +170,16 @@ def _exchange_strips(gp: jnp.ndarray, axis: int, base: int, off, ld: int,
             base + (off + ld) % ext, axis)
 
 
-def build_distributed_fn(stencil: Stencil, dims, iters: Optional[int],
+def build_distributed_fn(dag: DagSpec, dims, iters: Optional[int],
                          par_time: int, bsize, mesh: Mesh,
-                         axis_map: Sequence[Optional[Tuple[str, ...]]],
-                         kernel_stub: bool = False, *,
+                         axis_map: Sequence[Optional[Tuple[str, ...]]], *,
                          batch: bool = False, aux_batched: bool = False,
-                         trace_hook=None, bc=None, stages=None, dag=None,
-                         par_vec: int = 1, align: Tuple[int, ...] = (),
+                         trace_hook=None, par_vec: int = 1,
+                         align: Tuple[int, ...] = (),
                          block_parallel: bool = False):
-    """Build the jitted multi-device runner ``fn(grid, aux, coeffs) -> grid``.
+    """Build the jitted multi-device runner ``fn(grid, aux, coeffs) -> grid``
+    of the stage DAG ``dag`` (:class:`~repro.programs.DagSpec`; a single
+    stencil is ``chain_dag(((stencil, bc),))``).
 
     Used both for real execution (the ``distributed`` backend, tests,
     examples) and for the dry-run (``fn.lower(ShapeDtypeStruct...)``).
@@ -227,8 +189,14 @@ def build_distributed_fn(stencil: Stencil, dims, iters: Optional[int],
     extended block (module docstring); ``par_vec``, ``align`` and
     ``block_parallel`` are the kernel's.  The kernel is compiled on a mesh
     of TPUs and runs in the Pallas interpreter on any other.
-    ``kernel_stub=True`` instead routes each shard's super-step through the
-    opaque Pallas-kernel stand-in of the LM-era dry-run (``_superstep_stub``).
+
+    ``coeffs`` is one coefficient dict per stage.  The halo is
+    ``h = dag_radius(dag) * par_time``, the DAG's critical-path radius, so
+    one exchange covers the whole program per super-step; stage 0's BC
+    (the structural one) sizes the padding and picks, per axis, a ring
+    exchange where it is periodic.  A multi-field program's state carries
+    a leading ``(F, ...)`` field axis that is never sharded: one exchange
+    per sharded axis carries every field's strips.
 
     Throughput extensions (the serving path — see ``repro.api.backends``):
       * ``iters=None`` builds a *dynamic-iteration* runner
@@ -245,33 +213,17 @@ def build_distributed_fn(stencil: Stencil, dims, iters: Optional[int],
         or is shared by the whole batch.
       * ``trace_hook`` (if given) is called each time the local program is
         (re)traced — the executable cache's trace counter.
-      * ``bc`` (``core.boundary.BoundaryCondition``; None = clamp): per-axis
-        boundary condition; a sharded periodic axis exchanges on a ring.
-      * ``stages`` (a linear ``((stencil, bc), ...)`` program chain) or
-        ``dag`` (a :class:`~repro.programs.DagSpec`): the fused program;
-        ``stencil.radius`` is its critical-path radius, so one exchange of
-        ``h = radius * par_time`` still covers the whole program per
-        super-step; ``coeffs`` then is one dict per stage and ``bc`` the
-        program's structural (stage-0) BC.  A multi-field program's state
-        carries a leading ``(F, ...)`` field axis that is never sharded:
-        one exchange per sharded axis carries every field's strips.
     """
     if isinstance(bsize, int):
         bsize = (bsize,) * (len(dims) - 1)
     axis_map = tuple(tuple(a) if a else None for a in axis_map)
-    if kernel_stub:
-        if stages is not None or dag is not None or batch:
-            raise NotImplementedError(
-                "kernel_stub supports unbatched single-stage problems only")
-        return _build_stub_fn(stencil, dims, iters, par_time, bsize, mesh,
-                              axis_map, trace_hook, bc)
     from repro.core import boundary
     from repro.kernels import ops
     ndim = len(dims)
     sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
     sharded = tuple(bool(names) and math.prod(sizes[a] for a in names) > 1
                     for names in axis_map)
-    kinds = boundary.kinds_of(bc, ndim)
+    kinds = boundary.kinds_of(dag.stages[0][1], ndim)
     periodic = tuple(k == "periodic" for k in kinds)
 
     def localize(bc_s):
@@ -284,50 +236,24 @@ def build_distributed_fn(stencil: Stencil, dims, iters: Optional[int],
             "clamp" if (s and k == "periodic") else k
             for s, k in zip(sharded, bc_s.kinds)))
 
-    h = stencil.radius * par_time
+    radius = dag_radius(dag)
+    h = radius * par_time
     local_dims = shard_extents(dims, axis_map, mesh)
     for ax, (s, ld) in enumerate(zip(sharded, local_dims)):
         if s and ld < h:
             raise ValueError(
                 f"grid axis {ax}: a shard of {ld} cells cannot feed a halo "
-                f"of {h} (radius {stencil.radius} x par_time {par_time}); "
+                f"of {h} (radius {radius} x par_time {par_time}); "
                 f"lower par_time")
     geom = BlockGeometry(ndim, tuple(ld + 2 * h if s else ld
                                      for ld, s in zip(local_dims, sharded)),
-                         stencil.radius, par_time, tuple(bsize), par_vec,
+                         radius, par_time, tuple(bsize), par_vec,
                          tuple(align))
     interpret = mesh.devices.flat[0].platform != "tpu"
-    if dag is not None:
-        local_dag = dataclasses.replace(dag, stages=tuple(
-            (st, localize(bc_s), refs) for st, bc_s, refs in dag.stages))
-        has_aux = any(st.has_aux for st, _, _ in dag.stages)
-        bc0 = local_dag.stages[0][1]
-        n_fields = dag.n_fields
-
-        def loop(gp, cpk, iters_l, aux_p, **layout):
-            return ops.fused_dag_loop(local_dag, geom, gp, cpk, iters_l,
-                                      aux_p, interpret, block_parallel,
-                                      **layout)
-
-        def pack(coeffs):
-            return ops.pack_dag_coeffs(
-                dag, coeffs if isinstance(coeffs, tuple) else (coeffs,))
-    else:
-        chain = stages if stages is not None else ((stencil, bc),)
-        local_chain = tuple((st, localize(bc_s)) for st, bc_s in chain)
-        has_aux = any(st.has_aux for st, _ in chain)
-        bc0 = local_chain[0][1]
-        n_fields = 1
-
-        def loop(gp, cpk, iters_l, aux_p, **layout):
-            return ops.fused_chain_loop(local_chain, geom, gp, cpk, iters_l,
-                                        aux_p, interpret, block_parallel,
-                                        **layout)
-
-        def pack(coeffs):
-            if stages is None:
-                return ops.pack_coeffs(stencil, coeffs)
-            return ops.pack_program_coeffs(stages, coeffs)
+    local_dag = dataclasses.replace(dag, stages=tuple(
+        (st, localize(bc_s), refs) for st, bc_s, refs in dag.stages))
+    has_aux = any(st.has_aux for st, _, _ in dag.stages)
+    bc0 = local_dag.stages[0][1]
 
     sext = ops._stream_ext(geom, bc0)
     padded = ((geom.stream_slabs(geom.stream_dim + 2 * sext) * par_vec,)
@@ -366,10 +292,12 @@ def build_distributed_fn(stencil: Stencil, dims, iters: Optional[int],
         with jax.named_scope("stencil.pad"):
             gp = refresh(place(g))
             aux_p = refresh(place(aux_l)) if has_aux else None
-        return loop(gp, cpk, iters_l, aux_p, refresh=refresh, unpad=unpad)
+        return ops.fused_loop(local_dag, geom, gp, cpk, iters_l, aux_p,
+                              interpret, block_parallel, refresh=refresh,
+                              unpad=unpad)
 
     spec = partition_spec(axis_map)
-    off = (1 if batch else 0) + (1 if n_fields > 1 else 0)
+    off = (1 if batch else 0) + (1 if dag.n_fields > 1 else 0)
     aux_spec = P() if not has_aux else (
         P(None, *spec) if (batch and aux_batched) else spec)
     grid_spec = P(*((None,) * off), *spec) if off else spec
@@ -378,91 +306,14 @@ def build_distributed_fn(stencil: Stencil, dims, iters: Optional[int],
                              out_specs=grid_spec, check_vma=False)
     if iters is None:
         def run(g, aux, coeffs, iters_l):
-            return shmapped(g, aux, pack(coeffs), iters_l)
+            return shmapped(g, aux, ops.pack_coeffs(dag, coeffs), iters_l)
     else:
         # static-iters arity: fn(grid, aux, coeffs) (the dry-run/HLO paths)
         def run(g, aux, coeffs):
-            return shmapped(g, aux, pack(coeffs), jnp.int32(iters))
+            return shmapped(g, aux, ops.pack_coeffs(dag, coeffs),
+                            jnp.int32(iters))
     return jax.jit(run,
                    in_shardings=(NamedSharding(mesh, grid_spec),
                                  NamedSharding(mesh, aux_spec),
                                  None) + ((None,) if iters is None else ()),
                    out_shardings=NamedSharding(mesh, grid_spec))
-
-
-def _build_stub_fn(stencil: Stencil, dims, iters, par_time: int, bsize,
-                   mesh: Mesh, axis_map, trace_hook, bc):
-    """The LM-era dry-run's runner (``kernel_stub=True``): the shard is
-    extended by whole-array concatenation of the exchanged halos, and each
-    super-step is one opaque custom call (``_superstep_stub``) whose
-    ``bounds`` re-impose the boundary condition at the global edges."""
-    from repro.core import boundary
-    kinds = boundary.kinds_of(bc, len(dims))
-    bc_local = None if bc is None else dataclasses.replace(bc, kinds=tuple(
-        "clamp" if (names and kind == "periodic") else kind
-        for names, kind in zip(axis_map, kinds)))
-    h = stencil.radius * par_time
-    local_dims = shard_extents(dims, axis_map, mesh)
-    geom = BlockGeometry(len(dims), tuple(
-        ld + (2 * h if names else 0)
-        for ld, names in zip(local_dims, axis_map)), stencil.radius,
-        par_time, tuple(bsize))
-    has_aux = stencil.has_aux
-
-    def local_impl(g, aux_l, coeffs_l, iters_l):
-        if trace_hook is not None:
-            trace_hook()
-        n_super = (iters_l + par_time - 1) // par_time
-        bounds = []
-        for names, ld, kind in zip(axis_map, local_dims, kinds):
-            if names is None:
-                bounds.append((0, ld - 1))
-            elif kind == "periodic":
-                bounds.append((0, ld + 2 * h - 1))
-            else:
-                i, n = _linear_index(names), _axis_total(names)
-                bounds.append((jnp.where(i == 0, h, 0),
-                               jnp.where(i == n - 1, h + ld - 1,
-                                         ld + 2 * h - 1)))
-        keep = tuple(slice(h, h + ld) if names else slice(None)
-                     for names, ld in zip(axis_map, local_dims))
-
-        def extend(x):
-            for ax, names in enumerate(axis_map):
-                if names:
-                    x = _exchange_halo(x, ax, names, h,
-                                       periodic=kinds[ax] == "periodic")
-            return x
-        aux_ext = extend(aux_l) if has_aux else None
-
-        def superstep(s, gl):
-            steps = jnp.minimum(par_time, iters_l - s * par_time)
-            return _superstep_stub(stencil, geom, (extend(gl), keep),
-                                   coeffs_l, steps, aux_ext, tuple(bounds),
-                                   bc_local)
-
-        return jax.lax.fori_loop(0, n_super, superstep, g)
-
-    spec = partition_spec(axis_map)
-    aux_spec = spec if has_aux else P()
-
-    def local_run(g, aux_l, coeffs_l):
-        return local_impl(g, aux_l, coeffs_l, iters)
-    shmapped = jax.shard_map(local_run, mesh=mesh,
-                             in_specs=(spec, aux_spec, P()),
-                             out_specs=spec, check_vma=False)
-    return jax.jit(shmapped,
-                   in_shardings=(NamedSharding(mesh, spec),
-                                 NamedSharding(mesh, aux_spec), None),
-                   out_shardings=NamedSharding(mesh, spec))
-
-
-def distributed_run(stencil: Stencil, grid: jnp.ndarray, coeffs: dict,
-                    iters: int, par_time: int, bsize, mesh: Mesh,
-                    axis_map, aux: jnp.ndarray | None = None, *,
-                    bc=None) -> jnp.ndarray:
-    """Run ``iters`` steps of ``stencil`` on a grid sharded over ``mesh``."""
-    fn = build_distributed_fn(stencil, grid.shape, iters, par_time, bsize,
-                              mesh, axis_map, bc=bc)
-    aux_in = aux if aux is not None else jnp.zeros((), jnp.float32)
-    return fn(grid, aux_in, coeffs)

@@ -5,14 +5,15 @@ spatial blocks are materialized as a batch and updated ``par_time`` fused
 time-steps by a vmapped per-block pipeline, then the compute blocks are
 stitched back (out-of-bound compute is sliced off — the paper's "control only
 the flow of writes").  The Pallas kernels in ``repro.kernels`` implement the
-same math with explicit VMEM streaming; this module is their semantic spec
-and the multi-device distribution's local worker.
+same math with explicit VMEM streaming; this module is their semantic spec.
+Every problem runs as its stage DAG (:class:`repro.programs.DagSpec`; a
+single stencil is the one-stage chain).
 
 Boundary-condition handling across fused steps: see DESIGN.md §2.1 and
 ``core.boundary`` — local BCs (clamp/reflect/constant) are re-imposed on
-out-of-grid positions before every sub-step (``_reclamp``, now a BC-dispatch
-table), and the streaming axis uses BC-mode padding re-derived per sub-step
-(exact, because it is re-computed from current values).  Periodic axes need
+out-of-grid positions before every stage reads (``_reclamp``), and the
+streaming axis uses BC-mode padding re-derived per sub-step (exact,
+because it is re-computed from current values).  Periodic axes need
 no re-imposition at all: the super-step padding wraps (``mode="wrap"``), and
 a wrapped halo is an exact translated copy that stays exact up to the
 standard ``rad``-per-sub-step garbage creep — the same argument that makes
@@ -97,44 +98,26 @@ def _mask_fill(arr: jnp.ndarray, mask1d: jnp.ndarray, axis: int,
 
 
 def _reclamp(block: jnp.ndarray, bidx, geom: BlockGeometry,
-             bounds=None, bc=None) -> jnp.ndarray:
+             bc=None) -> jnp.ndarray:
     """Re-impose the (local) BC: overwrite out-of-grid positions per each
     axis' rule — clamp/reflect gather from the mapped in-grid coordinate,
     constant fills the scalar.  No-op for interior blocks; periodic axes are
     skipped entirely (their wrap-padded halos stay exact up to garbage
-    creep — see ``core.boundary``).
-
-    ``bounds``: optional (ndim, 2) physical-edge range per grid axis, in
-    grid coordinates — used by the multi-device runtime, where a shard's
-    local edge may be an *internal* boundary (no re-imposition: bounds cover
-    the whole halo-extended shard) or a *true* grid boundary (BC at the halo
-    offset). Entries may be traced. None = BC at the grid edges.
-    """
+    creep — see ``core.boundary``)."""
     kinds = boundary.kinds_of(bc, geom.ndim)
     value = boundary.fill_of(bc)
-    if bounds is not None and kinds[0] != "periodic":
-        # streaming axis (axis 0 of the block)
-        idx = jnp.arange(block.shape[0])
-        lo, hi = bounds[0]
-        if kinds[0] == "constant":
-            block = _mask_fill(block, boundary.out_of_range(idx, lo, hi),
-                               0, value)
-        else:
-            block = jnp.take(block, boundary.map_index(idx, lo, hi, kinds[0]),
-                             axis=0)
     for i, (dim, b, c, h) in enumerate(zip(geom.blocked_dims, geom.bsize,
                                            geom.csize, geom.pad)):
         kind = kinds[i + 1]
         if kind == "periodic":
             continue
         axis = block.ndim - (geom.ndim - 1) + i
-        lo, hi = (0, dim - 1) if bounds is None else bounds[i + 1]
         gx = bidx[i] * c + jnp.arange(b) - h
         if kind == "constant":
-            block = _mask_fill(block, boundary.out_of_range(gx, lo, hi),
+            block = _mask_fill(block, boundary.out_of_range(gx, 0, dim - 1),
                                axis, value)
         else:
-            jc = boundary.map_index(gx, lo, hi, kind) + h - bidx[i] * c
+            jc = boundary.map_index(gx, 0, dim - 1, kind) + h - bidx[i] * c
             block = jnp.take(block, jnp.clip(jc, 0, b - 1), axis=axis)
     return block
 
@@ -155,20 +138,8 @@ def _block_getter(block: jnp.ndarray, r: int, bc=None):
     return get
 
 
-def _block_substep(stencil: Stencil, block: jnp.ndarray, coeffs: dict,
-                   aux_block, bc=None) -> jnp.ndarray:
-    """One plain stencil step on a block (see :func:`_block_getter`).
-
-    Storage/accumulation policy (``repro.core.precision``): bf16 blocks
-    widen to f32 for the stage arithmetic and round back to storage once
-    per application; f32 passes through apply() untouched."""
-    get = _block_getter(block, stencil.radius, bc)
-    return precision.apply_stage(stencil, get, coeffs, aux_block,
-                                 block.dtype)
-
-
-def _block_substep_dag(stencil: Stencil, blocks, coeffs: dict,
-                       aux_block, bc=None) -> jnp.ndarray:
+def _block_substep(stencil: Stencil, blocks, coeffs: dict, aux_block,
+                   bc=None) -> jnp.ndarray:
     """One (possibly multi-input) stage application on pre-reclamped input
     blocks: each input is read under this stage's BC; ``arity > 1`` stencils
     receive a tuple of getters."""
@@ -179,54 +150,10 @@ def _block_substep_dag(stencil: Stencil, blocks, coeffs: dict,
         coeffs, aux_block, blocks[0].dtype)
 
 
-@partial(jax.jit, static_argnames=("stages", "geom"))
-def blocked_superstep_chain(stages, geom: BlockGeometry, grid: jnp.ndarray,
-                            stage_coeffs, steps,
-                            aux: jnp.ndarray | None = None,
-                            bounds=None) -> jnp.ndarray:
-    """Apply ``steps`` (<= par_time) fused *program iterations* — each one
-    the whole stage chain, in order — via one HBM round-trip worth of
-    overlapped blocks.
-
-    ``stages`` is the static ``((stencil, bc), ...)`` tuple (S=1 recovers
-    :func:`blocked_superstep` exactly); ``stage_coeffs`` one coefficient dict
-    per stage.  Block extraction pads under stage 0's BC (the BC the chain's
-    first read sees; periodicity is uniform across stages by construction)
-    and each stage re-imposes its own BC before it reads.  ``steps`` may be
-    a traced scalar; ``bounds`` is the optional per-axis physical-edge range
-    (see ``_reclamp``)."""
-    bc0 = stages[0][1]
-    has_aux = any(st.has_aux for st, _ in stages)
-    blocks = extract_blocks(grid, geom, bc0)
-    aux_blocks = extract_blocks(aux, geom, bc0) if has_aux else None
-    nb = geom.ndim - 1
-
-    def one_block(block, aux_block, *bidx):
-        def substep(t, blk):
-            cur = blk
-            for (st, bc_s), cf in zip(stages, stage_coeffs):
-                rec = _reclamp(cur, bidx, geom, bounds, bc_s)
-                new = _block_substep(st, rec, cf,
-                                     aux_block if st.has_aux else None, bc_s)
-                cur = jnp.where(t < steps, new, rec)   # PE forwarding
-            return cur
-        return jax.lax.fori_loop(0, geom.par_time, substep, block)
-
-    aux_ax = 0 if aux_blocks is not None else None
-    fn = one_block
-    for i in range(nb - 1, -1, -1):
-        fn = jax.vmap(fn, in_axes=(0, aux_ax)
-                      + tuple(0 if j == i else None for j in range(nb)))
-    upd = fn(blocks, aux_blocks,
-             *(jnp.arange(geom.bnum[j]) for j in range(nb)))
-    return stitch_blocks(upd, geom)
-
-
 @partial(jax.jit, static_argnames=("dag", "geom"))
-def blocked_superstep_dag(dag, geom: BlockGeometry, state: jnp.ndarray,
-                          stage_coeffs, steps,
-                          aux: jnp.ndarray | None = None,
-                          bounds=None) -> jnp.ndarray:
+def blocked_superstep(dag, geom: BlockGeometry, state: jnp.ndarray,
+                      stage_coeffs, steps,
+                      aux: jnp.ndarray | None = None) -> jnp.ndarray:
     """Apply ``steps`` (<= par_time) fused *program iterations* of a stage
     DAG (:class:`repro.programs.DagSpec`) via one HBM round-trip worth of
     overlapped blocks.
@@ -252,8 +179,8 @@ def blocked_superstep_dag(dag, geom: BlockGeometry, state: jnp.ndarray,
             for si in dag.topo:
                 st, bc_s, refs = dag.stages[si]
                 ins = [cur[~r] if r < 0 else vals[r] for r in refs]
-                recs = [_reclamp(x, bidx, geom, bounds, bc_s) for x in ins]
-                vals[si] = _block_substep_dag(
+                recs = [_reclamp(x, bidx, geom, bc_s) for x in ins]
+                vals[si] = _block_substep(
                     st, recs, stage_coeffs[si],
                     aux_block if st.has_aux else None, bc_s)
             out = []
@@ -277,39 +204,11 @@ def blocked_superstep_dag(dag, geom: BlockGeometry, state: jnp.ndarray,
     return jnp.stack(outs) if F > 1 else outs[0]
 
 
-def superstep_loop_dag(dag, geom: BlockGeometry, state: jnp.ndarray,
-                       stage_coeffs, iters,
-                       aux: jnp.ndarray | None = None,
-                       bounds=None) -> jnp.ndarray:
-    """Fused whole-run driver for a stage DAG — the DAG analogue of
-    :func:`superstep_loop_chain` (dynamic ``iters``, PE-forwarded partial
-    final super-step)."""
-    par_time = geom.par_time
-    n_super = (iters + par_time - 1) // par_time
-
-    def body(s, g):
-        steps = jnp.minimum(par_time, iters - s * par_time)
-        return blocked_superstep_dag(dag, geom, g, stage_coeffs, steps,
-                                     aux, bounds)
-
-    return jax.lax.fori_loop(0, n_super, body, state)
-
-
-def blocked_superstep(stencil: Stencil, geom: BlockGeometry,
-                      grid: jnp.ndarray, coeffs: dict, steps,
-                      aux: jnp.ndarray | None = None,
-                      bounds=None, bc=None) -> jnp.ndarray:
-    """Single-operator special case of :func:`blocked_superstep_chain`
-    (legacy entry point, semantics unchanged)."""
-    return blocked_superstep_chain(((stencil, bc),), geom, grid, (coeffs,),
-                                   steps, aux, bounds)
-
-
-def superstep_loop_chain(stages, geom: BlockGeometry, grid: jnp.ndarray,
-                         stage_coeffs, iters, aux: jnp.ndarray | None = None,
-                         bounds=None) -> jnp.ndarray:
-    """Fused whole-run driver for a stage chain: ``ceil(iters/par_time)``
-    super-steps as one traced loop (paper Eq. 8 numerator), so an enclosing
+def superstep_loop(dag, geom: BlockGeometry, state: jnp.ndarray,
+                   stage_coeffs, iters,
+                   aux: jnp.ndarray | None = None) -> jnp.ndarray:
+    """Fused whole-run driver: ``ceil(iters/par_time)`` super-steps of the
+    stage DAG as one traced loop (paper Eq. 8 numerator), so an enclosing
     ``jit`` lowers the entire iteration count to a single dispatch.
 
     ``iters`` may be a *traced* scalar: the trip count is computed inside the
@@ -317,42 +216,13 @@ def superstep_loop_chain(stages, geom: BlockGeometry, grid: jnp.ndarray,
     executable serves every iteration count — a serving process never
     re-traces because a request asked for a different ``iters``.  Trailing
     iterations of a partial final super-step are PE-forwarded (paper §3.2)
-    exactly as in :func:`blocked_superstep_chain`.
+    exactly as in :func:`blocked_superstep`.
     """
     par_time = geom.par_time
     n_super = (iters + par_time - 1) // par_time
 
     def body(s, g):
         steps = jnp.minimum(par_time, iters - s * par_time)
-        return blocked_superstep_chain(stages, geom, g, stage_coeffs, steps,
-                                       aux, bounds)
+        return blocked_superstep(dag, geom, g, stage_coeffs, steps, aux)
 
-    return jax.lax.fori_loop(0, n_super, body, grid)
-
-
-def superstep_loop(stencil: Stencil, geom: BlockGeometry, grid: jnp.ndarray,
-                   coeffs: dict, iters, aux: jnp.ndarray | None = None,
-                   bounds=None, bc=None) -> jnp.ndarray:
-    """Single-operator special case of :func:`superstep_loop_chain` (legacy
-    entry point, semantics unchanged)."""
-    return superstep_loop_chain(((stencil, bc),), geom, grid, (coeffs,),
-                                iters, aux, bounds)
-
-
-@partial(jax.jit, static_argnames=("stencil", "geom", "bc"))
-def _run_blocked_jit(stencil, geom, grid, coeffs, iters, aux, bc=None):
-    return superstep_loop(stencil, geom, grid, coeffs, iters, aux, bc=bc)
-
-
-def run_blocked(stencil: Stencil, grid: jnp.ndarray, coeffs: dict, iters: int,
-                par_time: int, bsize, aux: jnp.ndarray | None = None, *,
-                bc=None) -> jnp.ndarray:
-    """Full run: ceil(iters/par_time) super-steps (paper Eq. 8 numerator).
-
-    ``iters`` is passed into the executable as a dynamic scalar, so repeated
-    calls with different iteration counts share one compiled program."""
-    if isinstance(bsize, int):
-        bsize = (bsize,) * (grid.ndim - 1)
-    geom = BlockGeometry(grid.ndim, grid.shape, stencil.radius, par_time, bsize)
-    return _run_blocked_jit(stencil, geom, grid, coeffs,
-                            jnp.asarray(iters, jnp.int32), aux, bc)
+    return jax.lax.fori_loop(0, n_super, body, state)

@@ -4,8 +4,8 @@ A stencil is described by:
   * its neighborhood (radius + offsets used),
   * an ``apply`` function written against an abstract neighbor *getter*, so the
     same arithmetic is reused by the unblocked oracle (kernels/ref.py), the
-    pure-JAX blocked engine (core/engine.py) and the Pallas kernels
-    (kernels/stencil2d.py, stencil3d.py),
+    pure-JAX blocked engine (core/engine.py) and the Pallas kernel
+    (kernels/builder.py),
   * bookkeeping constants matching the paper's Table 2 (FLOP and bytes per
     cell update, external reads/writes per cell update).
 

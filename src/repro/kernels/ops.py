@@ -1,20 +1,17 @@
-"""Pallas dispatch + legacy entry-point shim.
+"""Pallas dispatch: the super-step loop the ``pallas``/``pallas_interpret``
+and ``distributed`` backends compile to, and the exact DMA-traffic
+accounting.
 
-The public API lives in ``repro.api`` (``StencilProblem`` -> ``plan()`` ->
-``StencilPlan``); this module keeps the Pallas super-step driver that the
-``pallas``/``pallas_interpret`` backends compile to, the exact DMA-traffic
-accounting, and ``stencil_run`` — the deprecated pre-``plan()`` entry point,
-now a thin shim.
-
-The Pallas path mirrors the engine's super-step loop: edge-pad the blocked
-dims, launch one kernel per super-step (``ceil(iters/par_time)``), slice the
-compute columns back out.  ``iters % par_time`` is handled in-kernel by PE
-forwarding, exactly like the paper's unused PEs.
+Every problem runs as its stage DAG (:class:`repro.programs.DagSpec`; a
+single stencil is the one-stage chain): :func:`run_pallas` edge-pads the
+blocked dims, :func:`fused_loop` launches one kernel per super-step
+(``ceil(iters/par_time)``) and slices the compute columns back out.
+``iters % par_time`` is handled in-kernel by PE forwarding, exactly like
+the paper's unused PEs.
 """
 from __future__ import annotations
 
 import math
-import warnings
 from functools import partial
 
 import jax
@@ -25,30 +22,18 @@ from jax import lax
 from repro.core import boundary
 from repro.core.blocking import BlockGeometry, stream_extension as _stream_ext
 from repro.core.stencils import Stencil
-from repro.kernels.builder import superstep_chain, superstep_dag
+from repro.kernels.builder import superstep_chain
 
 
-def pack_coeffs(stencil: Stencil, coeffs: dict) -> jnp.ndarray:
-    return jnp.stack([jnp.asarray(coeffs[n], jnp.float32)
-                      for n in stencil.coeff_names])
-
-
-def pack_program_coeffs(stages, stage_coeffs) -> jnp.ndarray:
-    """Concatenate per-stage coefficient vectors in *authored* stage order —
-    the layout :func:`repro.programs.unroll_dag` assigns ``coeff_lo``
-    offsets into.  ``stages`` is the static ``((stencil, bc), ...)`` tuple,
-    ``stage_coeffs`` one coefficient dict per stage."""
-    return jnp.concatenate([pack_coeffs(st, c)
-                            for (st, _), c in zip(stages, stage_coeffs)])
-
-
-def pack_dag_coeffs(dag, stage_coeffs) -> jnp.ndarray:
-    """DAG variant of :func:`pack_program_coeffs`: authored stage order of a
-    :class:`repro.programs.DagSpec` (evaluation order is the DAG's ``topo``
-    permutation, but coefficient packing stays positional)."""
-    return jnp.concatenate([pack_coeffs(st, c)
-                            for (st, _, _), c in zip(dag.stages,
-                                                     stage_coeffs)])
+def pack_coeffs(dag, stage_coeffs) -> jnp.ndarray:
+    """One flat f32 coefficient vector: each stage's coefficients in
+    ``coeff_names`` order, stages in *authored* order (the layout
+    :func:`repro.programs.unroll_dag` assigns ``coeff_lo`` offsets into;
+    evaluation order is the DAG's ``topo``).  ``stage_coeffs`` is one
+    coefficient dict per stage of ``dag``."""
+    return jnp.concatenate([
+        jnp.stack([jnp.asarray(c[n], jnp.float32) for n in st.coeff_names])
+        for (st, _, _), c in zip(dag.stages, stage_coeffs)])
 
 
 def _pad_blocked(grid: jnp.ndarray, geom: BlockGeometry,
@@ -192,32 +177,65 @@ def _per_member(kernel, gp: jnp.ndarray, aux_p, aux_rank: int):
     return jax.lax.map(lambda g: kernel(g, aux_p), gp)
 
 
-def _fused_loop(kernel, geom: BlockGeometry, gp: jnp.ndarray, iters,
-                aux_p, rank: int, refresh, unpad) -> jnp.ndarray:
-    """``ceil(iters / par_time)`` super-steps of ``kernel(g, steps, aux_p,
-    dst)`` over the padded state ``gp``, ``refresh`` rewriting the padding
-    strips after each, ``unpad`` taking the real cells out at the end.
+def fused_loop(dag, geom: BlockGeometry, gp: jnp.ndarray,
+               coeffs_packed: jnp.ndarray, iters,
+               aux_p: jnp.ndarray | None, interpret: bool,
+               block_parallel: bool = False, *, refresh=None,
+               unpad=None) -> jnp.ndarray:
+    """The whole ``iters`` loop of the stage DAG ``dag`` over the
+    *pre-padded* state ``gp`` (``(ns, *padded)`` single-field,
+    ``(F, ns, *padded)`` multi-field — every field padded identically),
+    returning the unpadded result: ``ceil(iters / par_time)`` super-steps
+    of the kernel, ``refresh`` rewriting the padding strips after each,
+    ``unpad`` taking the real cells out at the end.
 
-    The carry is two padded buffers the kernel alternates between: each
-    super-step reads one and writes into the other (``dst``, aliased to the
-    kernel's output), and ``refresh`` then writes that one's strips in
-    place.  A kernel cannot write into the buffer it reads (its blocks read
-    their neighbours' old cells as halo), and with one buffer XLA copies the
-    kernel's fresh output back into the carry before every kernel.  The
-    kernel rewrites every cell but the padding strips, which ``refresh``
-    rewrites (``kernels/builder._superstep_dag_impl``), so nothing a buffer
-    held two super-steps back survives into a result.
+    Why this shape:
+      * ``iters`` may be a traced scalar — the super-step trip count is
+        computed in-trace and the loop lowers to a dynamic ``while``, so one
+        compiled executable serves every iteration count (no per-``iters``
+        re-trace in a serving loop).
+      * The carry stays in the padded layout, in two buffers the kernel
+        alternates between: each super-step reads one and writes into the
+        other (``dst``, aliased to the kernel's output), and ``refresh``
+        then writes that one's strips in place.  A kernel cannot write into
+        the buffer it reads (its blocks read their neighbours' old cells as
+        halo), and with one buffer XLA copies the kernel's fresh output
+        back into the carry before every kernel.  The kernel rewrites every
+        cell but the padding strips, which ``refresh`` rewrites
+        (``kernels/builder.superstep_chain``), so nothing a buffer held two
+        super-steps back survives into a result.  ``gp`` is the first
+        buffer, so a caller that jits this function with ``donate_argnums``
+        on ``gp`` offers XLA the padded input for it — ``gp`` is an
+        intermediate the backend owns, so donation never invalidates a
+        caller-visible array.
+      * The carry's positions stay fixed (a swap would bring the copy
+        back), so super-steps run in pairs, A -> B then B -> A.  The first
+        super-step's own output, a fresh array, is the second buffer; a
+        trailing odd super-step and ``iters == 0`` are branches of a
+        ``lax.cond``, so ``iters`` stays traced.
 
-    The carry's positions stay fixed (a swap would bring the copy back), so
-    super-steps run in pairs, A -> B then B -> A.  The first super-step's own
-    output, a fresh array, is the second buffer; ``gp`` is the first; a
-    trailing odd super-step and ``iters == 0`` are branches of a
-    ``lax.cond``, so ``iters`` stays traced.  A ``gp`` with a leading batch
-    axis (more than ``rank`` axes) keeps one buffer: the kernel runs per
-    member (:func:`_per_member`), and ``lax.map`` has no buffer for it to
-    write into."""
+    ``refresh`` and ``unpad`` default to one chip's layout:
+    ``_reclamp_padded`` (halo, overhang and periodic stream extension of
+    every field under stage 0's BC: periodicity is uniform across stages by
+    construction, and each entry re-imposes its own BC in-kernel) and
+    ``_slice_blocked``.  A shard of a mesh passes its own
+    (``core/distributed.py``): the halo exchange with its neighbours plus
+    the strips of its physical edges.  A ``gp`` with a leading batch axis
+    keeps one buffer: the kernel runs per member between two refreshes
+    (:func:`_per_member`), and ``lax.map`` has no buffer for it to write
+    into.
+    """
+    bc0 = dag.stages[0][1]
+    refresh = refresh or partial(_reclamp_padded, geom=geom, bc=bc0)
+    unpad = unpad or partial(_slice_blocked, geom=geom, bc=bc0)
+    rank = geom.ndim + (dag.n_fields > 1)
     par_time = geom.par_time
     n_super = (iters + par_time - 1) // par_time
+
+    def kernel(g, steps, a, dst=None):
+        return superstep_chain(dag, geom, g, coeffs_packed, steps, a,
+                               interpret=interpret,
+                               block_parallel=block_parallel, dst=dst)
 
     def step(s, src, dst=None):
         steps = jnp.minimum(par_time, iters - s * par_time)
@@ -252,143 +270,25 @@ def _fused_loop(kernel, geom: BlockGeometry, gp: jnp.ndarray, iters,
     return jax.lax.cond(n_super > 0, run, lambda: unpadded(gp))
 
 
-def fused_chain_loop(stages, geom: BlockGeometry, gp: jnp.ndarray,
-                     coeffs_packed: jnp.ndarray, iters,
-                     aux_p: jnp.ndarray | None, interpret: bool,
-                     block_parallel: bool = False, *, refresh=None,
-                     unpad=None) -> jnp.ndarray:
-    """The throughput subsystem's fused driver: the whole ``iters`` loop of a
-    stage chain over the *pre-padded* grid ``gp``, returning the unpadded
-    result.  ``stages`` is the static ``((stencil, bc), ...)`` tuple of the
-    program (S=1 recovers the classic single-operator loop).
-
-    Why this shape:
-      * ``iters`` may be a traced scalar — the super-step trip count is
-        computed in-trace and the loop lowers to a dynamic ``while``, so one
-        compiled executable serves every iteration count (no per-``iters``
-        re-trace in a serving loop).
-      * The carry stays in the padded layout, in two buffers the kernel
-        alternates between (:func:`_fused_loop`): each kernel writes into
-        the buffer the previous one read, and ``refresh`` then writes only
-        that buffer's padding strips, in place, instead of a slice+re-pad
-        round-trip or any pass over the whole array.  ``gp`` is the first
-        buffer, so a caller that jits this function with ``donate_argnums``
-        on ``gp`` offers XLA the padded input for it — ``gp`` is an
-        intermediate the backend owns, so donation never invalidates a
-        caller-visible array.
-
-    ``refresh`` and ``unpad`` default to one chip's layout:
-    ``_reclamp_padded`` (halo, overhang and periodic stream extension under
-    stage 0's BC: the BC the chain's first entry reads the carry under —
-    periodicity is uniform across stages by construction, and each later
-    entry re-imposes its own BC in-kernel) and ``_slice_blocked``.  A shard
-    of a mesh passes its own (``core/distributed.py``): the halo exchange
-    with its neighbours plus the strips of its physical edges.  A ``gp``
-    with a leading batch axis runs the kernel per member between two
-    refreshes (:func:`_per_member`).
-    """
-    bc0 = stages[0][1]
-    refresh = refresh or partial(_reclamp_padded, geom=geom, bc=bc0)
-    unpad = unpad or partial(_slice_blocked, geom=geom, bc=bc0)
-
-    def kernel(g, steps, a, dst=None):
-        return superstep_chain(stages, geom, g, coeffs_packed, steps, a,
-                               interpret=interpret,
-                               block_parallel=block_parallel, dst=dst)
-
-    return _fused_loop(kernel, geom, gp, iters, aux_p, geom.ndim, refresh,
-                       unpad)
-
-
-def fused_dag_loop(dag, geom: BlockGeometry, gp: jnp.ndarray,
-                   coeffs_packed: jnp.ndarray, iters,
-                   aux_p: jnp.ndarray | None, interpret: bool,
-                   block_parallel: bool = False, *, refresh=None,
-                   unpad=None) -> jnp.ndarray:
-    """DAG analogue of :func:`fused_chain_loop`: the whole ``iters`` loop of
-    a stage DAG (:class:`repro.programs.DagSpec`) over the *pre-padded*
-    state ``gp`` (``(ns, *padded)`` single-field, ``(F, ns, *padded)``
-    multi-field — every field padded identically), returning the unpadded
-    result.  The carry stays padded, in two buffers the kernel alternates
-    between (:func:`_fused_loop`; every field of the buffer it writes is
-    rewritten but the padding strips); by default the padding strips of all
-    fields are rewritten in place by one ``_reclamp_padded`` per super-step
-    under stage 0's BC (periodicity is uniform by construction; each entry
-    re-imposes its own BC in-kernel); ``refresh``, ``unpad`` and a leading
-    batch axis as in :func:`fused_chain_loop`."""
-    bc0 = dag.stages[0][1]
-    refresh = refresh or partial(_reclamp_padded, geom=geom, bc=bc0)
-    unpad = unpad or partial(_slice_blocked, geom=geom, bc=bc0)
-
-    def kernel(g, steps, a, dst=None):
-        return superstep_dag(dag, geom, g, coeffs_packed, steps, a,
-                             interpret=interpret,
-                             block_parallel=block_parallel, dst=dst)
-
-    rank = geom.ndim + (dag.n_fields > 1)
-    return _fused_loop(kernel, geom, gp, iters, aux_p, rank, refresh, unpad)
-
-
-def fused_superstep_loop(stencil: Stencil, geom: BlockGeometry,
-                         gp: jnp.ndarray, coeffs_packed: jnp.ndarray, iters,
-                         aux_p: jnp.ndarray | None, interpret: bool,
-                         bc=None, block_parallel: bool = False) -> jnp.ndarray:
-    """Single-operator special case of :func:`fused_chain_loop` (legacy
-    entry point, semantics unchanged)."""
-    return fused_chain_loop(((stencil, bc),), geom, gp, coeffs_packed, iters,
-                            aux_p, interpret, block_parallel)
-
-
-@partial(jax.jit, static_argnames=("stencil", "geom", "interpret", "bc",
-                                   "block_parallel"))
-def run_pallas(stencil: Stencil, geom: BlockGeometry, grid: jnp.ndarray,
-               coeffs_packed: jnp.ndarray, iters,
-               aux: jnp.ndarray | None, interpret: bool,
-               bc=None, block_parallel: bool = False) -> jnp.ndarray:
-    """``iters`` time-steps via the streaming Pallas kernels.
-
-    ``iters`` is dynamic (traced): one executable per (stencil, geom, bc)
-    serves all iteration counts — see :func:`fused_superstep_loop`."""
-    with jax.named_scope("stencil.pad"):
-        aux_p = _pad_blocked(aux, geom, bc) if aux is not None else None
-        gp = _pad_blocked(grid, geom, bc)
-    return fused_superstep_loop(stencil, geom, gp, coeffs_packed, iters,
-                                aux_p, interpret, bc, block_parallel)
-
-
-@partial(jax.jit, static_argnames=("stages", "geom", "interpret",
-                                   "block_parallel"))
-def run_pallas_chain(stages, geom: BlockGeometry, grid: jnp.ndarray,
-                     coeffs_packed: jnp.ndarray, iters,
-                     aux: jnp.ndarray | None, interpret: bool,
-                     block_parallel: bool = False) -> jnp.ndarray:
-    """``iters`` program iterations via the fused streaming chain kernel.
-    ``stages`` is the static ``((stencil, bc), ...)`` tuple; padding uses
-    stage 0's BC (see :func:`fused_chain_loop`)."""
-    bc0 = stages[0][1]
-    with jax.named_scope("stencil.pad"):
-        aux_p = _pad_blocked(aux, geom, bc0) if aux is not None else None
-        gp = _pad_blocked(grid, geom, bc0)
-    return fused_chain_loop(stages, geom, gp, coeffs_packed, iters, aux_p,
-                            interpret, block_parallel)
-
-
 @partial(jax.jit, static_argnames=("dag", "geom", "interpret",
                                    "block_parallel"))
-def run_pallas_dag(dag, geom: BlockGeometry, state: jnp.ndarray,
-                   coeffs_packed: jnp.ndarray, iters,
-                   aux: jnp.ndarray | None, interpret: bool,
-                   block_parallel: bool = False) -> jnp.ndarray:
-    """``iters`` program iterations via the fused streaming DAG kernel.
-    ``state`` is the plain grid for single-field programs, else the
-    ``(F, *shape)`` field stack (the leading field axis rides through
-    ``_pad_blocked`` like a batch axis); padding uses stage 0's BC."""
+def run_pallas(dag, geom: BlockGeometry, state: jnp.ndarray,
+               coeffs_packed: jnp.ndarray, iters,
+               aux: jnp.ndarray | None, interpret: bool,
+               block_parallel: bool = False) -> jnp.ndarray:
+    """``iters`` program iterations of ``dag`` via the streaming Pallas
+    kernel.  ``state`` is the plain grid for single-field programs, else
+    the ``(F, *shape)`` field stack (the leading field axis rides through
+    ``_pad_blocked`` like a batch axis); padding uses stage 0's BC.
+
+    ``iters`` is dynamic (traced): one executable per (dag, geom) serves
+    all iteration counts — see :func:`fused_loop`."""
     bc0 = dag.stages[0][1]
     with jax.named_scope("stencil.pad"):
         aux_p = _pad_blocked(aux, geom, bc0) if aux is not None else None
         gp = _pad_blocked(state, geom, bc0)
-    return fused_dag_loop(dag, geom, gp, coeffs_packed, iters, aux_p,
-                          interpret, block_parallel)
+    return fused_loop(dag, geom, gp, coeffs_packed, iters, aux_p, interpret,
+                      block_parallel)
 
 
 def dma_traffic_bytes(stencil: Stencil, geom: BlockGeometry,
@@ -427,23 +327,3 @@ def dma_traffic_bytes(stencil: Stencil, geom: BlockGeometry,
     reads = n_blocks * stream * block_in * stencil.num_read
     writes = n_blocks * stream * block_out * stencil.num_write
     return (reads + writes) * cell_bytes
-
-
-def stencil_run(stencil: Stencil, grid: jnp.ndarray, coeffs: dict, iters: int,
-                par_time: int, bsize, aux: jnp.ndarray | None = None,
-                backend: str = "pallas_interpret") -> jnp.ndarray:
-    """Deprecated: use ``repro.api.plan`` instead.
-
-    Thin shim over ``plan(StencilProblem(...), RunConfig(...)).run(...)``,
-    kept for old call sites.  Results are identical to the plan path.
-    """
-    warnings.warn(
-        "stencil_run is deprecated; use repro.api.plan(StencilProblem(...), "
-        "RunConfig(backend=...)).run(grid, iters, coeffs, aux=aux)",
-        DeprecationWarning, stacklevel=2)
-    from repro.api import RunConfig, StencilProblem, plan
-    grid = jnp.asarray(grid)
-    problem = StencilProblem(stencil, tuple(grid.shape),
-                             dtype=grid.dtype.name)   # legacy: dtype-generic
-    config = RunConfig(backend=backend, par_time=par_time, bsize=bsize)
-    return plan(problem, config).run(grid, iters, coeffs, aux=aux)

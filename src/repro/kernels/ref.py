@@ -14,6 +14,7 @@ import jax.numpy as jnp
 
 from repro.core import boundary, precision
 from repro.core.stencils import Stencil
+from repro.programs import chain_dag
 
 
 def _padded_getter(grid: jnp.ndarray, r: int, bc=None):
@@ -30,48 +31,6 @@ def _padded_getter(grid: jnp.ndarray, r: int, bc=None):
         return p[idx]
 
     return get
-
-
-def oracle_step(stencil: Stencil, grid: jnp.ndarray, coeffs: dict,
-                aux: jnp.ndarray | None = None, *, bc=None) -> jnp.ndarray:
-    """One time-step over the full grid under ``bc`` (default: clamp).
-
-    Storage/accumulation policy (``repro.core.precision``): sub-32-bit
-    grids (bf16) widen to f32 for the stage arithmetic and round back to
-    storage once per application; f32 passes through apply() untouched."""
-    get = _padded_getter(grid, stencil.radius, bc)
-    return precision.apply_stage(stencil, get, coeffs, aux, grid.dtype)
-
-
-def oracle_run(stencil: Stencil, grid: jnp.ndarray, coeffs: dict,
-               iters: int, aux: jnp.ndarray | None = None, *,
-               bc=None) -> jnp.ndarray:
-    """``iters`` time-steps (double-buffered in the caller's imagination —
-    functionally pure here)."""
-    def body(_, g):
-        return oracle_step(stencil, g, coeffs, aux, bc=bc)
-    return jax.lax.fori_loop(0, iters, body, grid)
-
-
-def oracle_program_step(stages, grid: jnp.ndarray, stage_coeffs,
-                        aux: jnp.ndarray | None = None) -> jnp.ndarray:
-    """One *program iteration*: apply every stage in order, each under its
-    own BC.  ``stages`` is ``((stencil, bc), ...)``, ``stage_coeffs`` one
-    coefficient dict per stage — the sequential semantics every fused chain
-    backend is conformance-tested against."""
-    for (st, bc_s), cf in zip(stages, stage_coeffs):
-        grid = oracle_step(st, grid, cf, aux if st.has_aux else None,
-                           bc=bc_s)
-    return grid
-
-
-def oracle_program_run(stages, grid: jnp.ndarray, stage_coeffs,
-                       iters: int, aux: jnp.ndarray | None = None
-                       ) -> jnp.ndarray:
-    """``iters`` program iterations of the stage chain."""
-    def body(_, g):
-        return oracle_program_step(stages, g, stage_coeffs, aux)
-    return jax.lax.fori_loop(0, iters, body, grid)
 
 
 def oracle_dag_step(dag, state: jnp.ndarray, stage_coeffs,
@@ -102,3 +61,12 @@ def oracle_dag_run(dag, state: jnp.ndarray, stage_coeffs, iters: int,
     def body(_, s):
         return oracle_dag_step(dag, s, stage_coeffs, aux)
     return jax.lax.fori_loop(0, iters, body, state)
+
+
+def oracle_run(stencil: Stencil, grid: jnp.ndarray, coeffs: dict,
+               iters: int, aux: jnp.ndarray | None = None, *,
+               bc=None) -> jnp.ndarray:
+    """``iters`` time-steps of a single stencil (the one-stage DAG
+    :func:`oracle_dag_run`)."""
+    return oracle_dag_run(chain_dag(((stencil, bc),)), grid, (coeffs,),
+                          iters, aux)

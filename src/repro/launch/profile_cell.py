@@ -24,13 +24,11 @@ def main():
     ap.add_argument("--mesh", default="single", choices=["single", "multi"])
     ap.add_argument("--top", type=int, default=14)
     ap.add_argument("--attn", default=None, choices=[None, "xla", "stub"])
-    ap.add_argument("--kernel-stub", action="store_true",
-                    help="stencil cells: bill the Pallas kernel's DMA")
     args = ap.parse_args()
 
     if args.arch in STENCIL_IDS:
         mesh, st, fn, cell_args, best = build_stencil_cell(
-            args.arch, args.mesh == "multi", kernel_stub=args.kernel_stub)
+            args.arch, args.mesh == "multi")
     else:
         mesh, cfg, fn, cell_args = build_cell(args.arch, args.shape,
                                               args.mesh == "multi",

@@ -15,9 +15,10 @@ device, where ``jax.named_scope`` labels its phases in the compiled
 program's ``op_name`` metadata instead (``kernels/ops.py``):
 
 * ``stencil.pad``           edge-padding the grid and the aux field into the
-                            blocked layout, in the jitted ``run_pallas*``
-                            entry points (the Pallas backend pads op by op
-                            before its executable, where no scope reaches);
+                            blocked layout, in the jitted ``run_pallas``
+                            and in each shard of the distributed backend
+                            (the Pallas backend pads op by op before its
+                            executable, where no scope reaches);
 * ``stencil.superstep``     the fused streaming kernel of one super-step;
 * ``stencil.halo_refresh``  rewriting the padded carry's padding strips
                             in place between super-steps;

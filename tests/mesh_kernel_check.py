@@ -24,7 +24,7 @@ import numpy as np  # noqa: E402
 from repro.api import (RunConfig, StencilProblem, StencilStage,  # noqa: E402
                        plan)
 from repro.core.stencils import make_combine, make_star  # noqa: E402
-from repro.kernels.ref import oracle_dag_run, oracle_run  # noqa: E402
+from repro.kernels.ref import oracle_dag_run  # noqa: E402
 
 
 def _wave():
@@ -86,13 +86,9 @@ def _inputs(problem, batch, seed):
 
 
 def _oracle(problem, grid, iters, aux):
-    if problem.is_dag:
-        return oracle_dag_run(problem.exec_dag, grid,
-                              problem.resolve_coeffs(dtype=jnp.float32),
-                              iters, aux)
-    st, bc = problem.exec_stages[0]
-    return oracle_run(st, grid, problem.resolve_coeffs(
-        dtype=jnp.float32)[0], iters, aux, bc=bc)
+    return oracle_dag_run(problem.exec_dag, grid,
+                          problem.resolve_coeffs(dtype=jnp.float32), iters,
+                          aux)
 
 
 def run_case(name, seed):

@@ -11,9 +11,19 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.api import RunConfig, StencilProblem, plan
 from repro.core import STENCILS, default_coeffs
-from repro.core.distributed import distributed_run
 from repro.kernels.ref import oracle_run
+
+
+def distributed(name, g, c, iters, par_time, bsize, mesh, axis_map,
+                aux=None):
+    """``iters`` steps of the named stencil through the distributed
+    backend's plan, sharded over ``mesh`` per ``axis_map``."""
+    p = plan(StencilProblem(name, g.shape),
+             RunConfig(backend="distributed", par_time=par_time,
+                       bsize=bsize, mesh=mesh, axis_map=axis_map))
+    return p.run(g, iters, c, aux=aux)
 
 
 def check_2d():
@@ -24,8 +34,8 @@ def check_2d():
     c = default_coeffs(st)
     for iters, pt in [(1, 1), (4, 2), (5, 2)]:
         want = oracle_run(st, g, c, iters)
-        got = distributed_run(st, g, c, iters, pt, (24,), mesh,
-                              (("data",), ("model",)))
+        got = distributed("diffusion2d", g, c, iters, pt, (24,), mesh,
+                          (("data",), ("model",)))
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=3e-5, atol=3e-5)
     print("2d ok")
@@ -42,8 +52,8 @@ def check_2d_joint_axes():
                              0.0, 0.1)
     c = default_coeffs(st)
     want = oracle_run(st, g, c, 4, aux)
-    got = distributed_run(st, g, c, 4, 2, (16,), mesh,
-                          (("pod", "data"), ("model",)), aux=aux)
+    got = distributed("hotspot2d", g, c, 4, 2, (16,), mesh,
+                      (("pod", "data"), ("model",)), aux=aux)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=3e-5, atol=3e-5)
     print("2d joint-axes ok")
@@ -56,8 +66,8 @@ def check_3d():
     g = jax.random.uniform(jax.random.PRNGKey(2), dims, jnp.float32, 0.5, 2.0)
     c = default_coeffs(st)
     want = oracle_run(st, g, c, 4)
-    got = distributed_run(st, g, c, 4, 2, (12, 12), mesh,
-                          (("pod",), ("data",), ("model",)))
+    got = distributed("diffusion3d", g, c, 4, 2, (12, 12), mesh,
+                      (("pod",), ("data",), ("model",)))
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=3e-5, atol=3e-5)
     print("3d ok")

@@ -15,7 +15,7 @@ import numpy as np
 
 from repro.api import RunConfig, StencilProblem, StencilStage, plan
 from repro.core.stencils import make_star
-from repro.kernels.ref import oracle_program_run
+from repro.kernels.ref import oracle_dag_run
 
 
 def check_program():
@@ -26,8 +26,8 @@ def check_program():
     prob = StencilProblem(
         [StencilStage(make_star(2, 1)), StencilStage("diffusion2d")],
         shape, boundary=("clamp", "periodic"))
-    want = oracle_program_run(prob.exec_stages, g,
-                              prob.resolve_coeffs(dtype=jnp.float32), 5)
+    want = oracle_dag_run(prob.exec_dag, g,
+                          prob.resolve_coeffs(dtype=jnp.float32), 5)
     p = plan(prob, RunConfig(backend="distributed", mesh=mesh,
                              par_time=2, bsize=12))
     got = p.run(g, iters=5)
@@ -39,8 +39,8 @@ def check_program():
                             0.5, 2.0)
     outs = p.run_batch(gs, iters=4)
     wants = jnp.stack([
-        oracle_program_run(prob.exec_stages, gs[i],
-                           prob.resolve_coeffs(dtype=jnp.float32), 4)
+        oracle_dag_run(prob.exec_dag, gs[i],
+                       prob.resolve_coeffs(dtype=jnp.float32), 4)
         for i in range(3)])
     np.testing.assert_allclose(np.asarray(outs), np.asarray(wants),
                                rtol=3e-5, atol=3e-5)

@@ -2,11 +2,10 @@
 
 Covers the acceptance surface of the API redesign: cross-backend equivalence
 through one ``plan()`` call, plan reuse across iteration counts, perf-model
-autotuning under the VMEM budget, the ``stencil_run`` deprecation shim, the
-backend registry, and the small-grid autotune regression.
+autotuning under the VMEM budget, every problem running as its stage DAG,
+the backend registry, and the small-grid autotune regression.
 """
 import dataclasses
-import warnings
 
 import jax
 import jax.numpy as jnp
@@ -18,7 +17,7 @@ from repro.api import (RunConfig, StencilPlan, StencilProblem, get_backend,
 from repro.core import STENCILS, default_coeffs
 from repro.core.blocking import bsize_feasible, choose_bsize_candidates
 from repro.core.perf_model import TPU_V5E, autotune
-from repro.kernels.ref import oracle_run
+from repro.kernels.ref import oracle_dag_run, oracle_run
 
 
 def _data(stencil, dims, seed=0):
@@ -158,7 +157,7 @@ def test_autotune_exposes_ranked_candidates():
 
 def test_reference_plan_tolerates_unresolvable_schedule():
     """The oracle ignores blocking: an infeasible schedule degrades the plan
-    to geometry-less instead of raising (legacy stencil_run semantics)."""
+    to geometry-less instead of raising."""
     st = STENCILS["diffusion2d"]
     g, _ = _data(st, (32, 48))
     c = default_coeffs(st)
@@ -231,47 +230,43 @@ def test_predict_halo_follows_chip_grid():
                                       / TPU_V5E.ici_bw)
 
 
-# --- deprecation shim ---------------------------------------------------------
+# --- one execution path: every problem runs as its stage DAG ----------------
 
-def test_stencil_run_shim_warns_and_matches():
-    from repro.kernels.ops import stencil_run
+@pytest.mark.parametrize("backend", ["reference", "engine",
+                                     "pallas_interpret"])
+def test_every_problem_runs_as_its_dag(backend):
+    """A bare stencil, the same stencil as a one-stage program with static
+    coefficient overrides, and a two-stage chain all run bit-identical to
+    the DAG oracle, and a linear problem's DAG is its stage chain."""
+    from repro.api import StencilStage
+    from repro.core.stencils import make_star
+    from repro.programs import chain_dag
+    dims = (21, 45)
     st = STENCILS["diffusion2d"]
-    g, _ = _data(st, (21, 45))
-    c = default_coeffs(st)
-    p = plan(StencilProblem("diffusion2d", (21, 45)),
-             RunConfig(backend="engine", par_time=2, bsize=24))
-    want = p.run(g, 5, c)
-    with warnings.catch_warnings(record=True) as caught:
-        warnings.simplefilter("always")
-        got = stencil_run(st, g, c, 5, 2, 24, backend="engine")
-    assert any(issubclass(w.category, DeprecationWarning) for w in caught)
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
-
-
-def test_stencil_run_shim_preserves_dtype():
-    """Legacy stencil_run was dtype-generic; the shim must not coerce."""
-    from repro.kernels.ops import stencil_run
-    st = STENCILS["diffusion2d"]
-    g = jnp.ones((12, 20), jnp.bfloat16)
-    c = {k: jnp.asarray(v, jnp.bfloat16)
-         for k, v in default_coeffs(st).items()}
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore", DeprecationWarning)
-        got = stencil_run(st, g, c, 2, 1, 8, backend="engine")
-    assert got.dtype == jnp.bfloat16
-
-
-def test_stencil_run_shim_reference_ignores_bad_geometry():
-    """Legacy behavior: the oracle path never validated (par_time, bsize)."""
-    from repro.kernels.ops import stencil_run
-    st = STENCILS["diffusion2d"]
-    g, _ = _data(st, (12, 20))
-    c = default_coeffs(st)
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore", DeprecationWarning)
-        got = stencil_run(st, g, c, 3, 16, 8, backend="reference")
-    want = oracle_run(st, g, c, 3)
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    g, _ = _data(st, dims)
+    override = {"cc": 0.5, "cn": 0.2}
+    problems = [
+        StencilProblem("diffusion2d", dims, boundary=("clamp", "reflect")),
+        StencilProblem(StencilStage(st, coeffs=override), dims,
+                       boundary=("clamp", "reflect")),
+        StencilProblem([StencilStage(make_star(2, 1)),
+                        StencilStage("diffusion2d")], dims,
+                       boundary=("clamp", "reflect")),
+    ]
+    assert not problems[0].is_program and problems[1].is_program
+    pv = 4 if backend == "pallas_interpret" else None
+    for problem in problems:
+        assert not problem.is_dag
+        assert problem.exec_dag == chain_dag(problem.exec_stages)
+        p = plan(problem, RunConfig(backend=backend, par_time=2, bsize=24,
+                                    par_vec=pv))
+        want = oracle_dag_run(problem.exec_dag, g,
+                              problem.resolve_coeffs(dtype=jnp.float32), 5)
+        np.testing.assert_array_equal(np.asarray(p.run(g, 5)),
+                                      np.asarray(want))
+    # the static override reached the stage: not the bare stencil's result
+    assert not np.array_equal(np.asarray(plan(problems[0]).run(g, 2)),
+                              np.asarray(plan(problems[1]).run(g, 2)))
 
 
 # --- backend registry ---------------------------------------------------------
@@ -294,8 +289,9 @@ def test_register_custom_backend():
 
     def doubling_oracle(problem, config, geom):
         def execute(grid, coeffs, iters, aux=None):
+            # the payload is one coefficient dict per stage of the DAG
             calls.append(iters)
-            return oracle_run(problem.stencil, grid, coeffs, iters, aux)
+            return oracle_dag_run(problem.exec_dag, grid, coeffs, iters, aux)
         return execute
 
     register_backend("test_custom", doubling_oracle)

@@ -209,13 +209,13 @@ def test_aligned_geometry_matches_oracle(bc, name, dims, par_time, csize,
     assert dims[0] % par_vec or name.endswith("3d")
     problem = StencilProblem(st, dims, dtype=dtype, boundary=bc)
     stage, bc_obj = problem.exec_stages[0]
+    dag = problem.exec_dag
     g, aux = _data(st, dims)
     g = g.astype(dtype)
     aux = None if aux is None else aux.astype(dtype)
-    c = problem.resolve_coeffs(dtype=jnp.float32)[0]
-    want = oracle_run(stage, g, c, 7, aux, bc=bc_obj)
-    got = run_pallas(stage, geom, g, pack_coeffs(stage, c), 7, aux, True,
-                     bc=bc_obj)
+    c = problem.resolve_coeffs(dtype=jnp.float32)
+    want = oracle_run(stage, g, c[0], 7, aux, bc=bc_obj)
+    got = run_pallas(dag, geom, g, pack_coeffs(dag, c), 7, aux, True)
     np.testing.assert_array_equal(np.asarray(got, np.float32),
                                   np.asarray(want, np.float32))
 
@@ -225,7 +225,7 @@ def test_aligned_wave_dag_matches_oracle():
     layout, with a periodic stream extension."""
     from repro.api import StencilProgram, StencilStage
     from repro.core.stencils import make_combine
-    from repro.kernels.ops import pack_dag_coeffs, run_pallas_dag
+    from repro.kernels.ops import pack_coeffs, run_pallas
     from repro.kernels.ref import oracle_dag_run
     lap = StencilStage(make_star(2, 1), name="lapu", inputs=("u",),
                        coeffs={"c0": -4.0, "c_0_-1": 1.0, "c_0_1": 1.0,
@@ -243,8 +243,8 @@ def test_aligned_wave_dag_matches_oracle():
     coeffs = problem.resolve_coeffs(dtype=jnp.float32)
     geom = _aligned_geom(make_star(2, 1), dims, 2, (128,), 8)
     want = oracle_dag_run(dag, state, coeffs, 5, None)
-    got = run_pallas_dag(dag, geom, state, pack_dag_coeffs(dag, coeffs), 5,
-                         None, True)
+    got = run_pallas(dag, geom, state, pack_coeffs(dag, coeffs), 5, None,
+                     True)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 

@@ -33,7 +33,7 @@ from repro.api import (RunConfig, StencilProblem, StencilProgram,
 from repro.api.backends import _exec_key
 from repro.api.schedule_cache import schedule_key, stencil_fingerprint
 from repro.core.stencils import STENCILS, make_star
-from repro.kernels.ref import oracle_program_run
+from repro.kernels.ref import oracle_dag_run
 
 BACKENDS = ("reference", "engine", "pallas_interpret")
 
@@ -55,7 +55,7 @@ def test_two_stage_program_matches_oracle(backend, shape, bsize):
             StencilStage(f"diffusion{ndim}d")]
     problem = StencilProblem(prog, shape, boundary="clamp")
     g, _ = _inputs(jax.random.PRNGKey(0), shape)
-    want = oracle_program_run(problem.exec_stages, g,
+    want = oracle_dag_run(problem.exec_dag, g,
                               problem.resolve_coeffs(dtype=jnp.float32), 5)
     p = plan(problem, RunConfig(backend=backend, par_time=2, bsize=bsize,
                                 par_vec=1))
@@ -97,7 +97,7 @@ def test_radius_zero_stage():
     problem = StencilProblem([StencilStage("diffusion2d"), damp], shape)
     assert problem.stencil.radius == 1          # rad sums; the 0 is free
     g, _ = _inputs(jax.random.PRNGKey(2), shape)
-    want = oracle_program_run(problem.exec_stages, g,
+    want = oracle_dag_run(problem.exec_dag, g,
                               problem.resolve_coeffs(dtype=jnp.float32), 6)
     for backend in BACKENDS:
         p = plan(problem, RunConfig(backend=backend, par_time=2, bsize=8,
@@ -120,7 +120,7 @@ def test_per_stage_coeffs_and_runtime_override():
     p = plan(problem, RunConfig(backend="engine", par_time=1, bsize=8))
     g, _ = _inputs(jax.random.PRNGKey(3), shape)
     override = ({"c0": 0.7}, None)
-    want = oracle_program_run(problem.exec_stages, g,
+    want = oracle_dag_run(problem.exec_dag, g,
                               problem.resolve_coeffs(override,
                                                      dtype=jnp.float32), 3)
     np.testing.assert_allclose(np.asarray(p.run(g, iters=3, coeffs=override)),
@@ -142,7 +142,7 @@ def test_program_run_batch(backend):
     gs = jax.random.uniform(jax.random.PRNGKey(4), (3,) + shape, jnp.float32,
                             0.5, 2.0)
     cf = problem.resolve_coeffs(dtype=jnp.float32)
-    want = jnp.stack([oracle_program_run(problem.exec_stages, gs[i], cf, 4)
+    want = jnp.stack([oracle_dag_run(problem.exec_dag, gs[i], cf, 4)
                       for i in range(3)])
     np.testing.assert_allclose(np.asarray(p.run_batch(gs, iters=4)),
                                np.asarray(want), rtol=2e-5, atol=2e-5)
@@ -179,7 +179,7 @@ def _chain_case(params):
     problem = StencilProblem(StencilProgram(tuple(stages)), shape,
                              boundary="clamp")
     g, _ = _inputs(jax.random.PRNGKey(seed), shape)
-    want = oracle_program_run(problem.exec_stages, g,
+    want = oracle_dag_run(problem.exec_dag, g,
                               problem.resolve_coeffs(dtype=jnp.float32),
                               iters)
     p = plan(problem, RunConfig(backend=backend, par_time=par_time,
@@ -564,32 +564,25 @@ def test_random_dag_matches_oracle(case):
                                want, rtol=3e-5, atol=3e-5)
 
 
-# --- the linear fast path IS the DAG path -------------------------------------
+# --- a linear program runs as its chain DAG ---------------------------------
 
 def test_linear_chain_bit_identical_through_dag_executor():
-    """A linear program run through the chain wrapper and through the DAG
-    wrapper (its chain_dag form) must agree BIT FOR BIT — the acceptance
-    criterion that the refactor left PR 6's linear kernels untouched."""
-    from repro.core.blocking import BlockGeometry
-    from repro.kernels.ops import (pack_dag_coeffs, pack_program_coeffs,
-                                   run_pallas_chain, run_pallas_dag)
+    """A linear program's DAG is its stage chain, and ``plan().run`` of the
+    fused kernel equals the sequential DAG oracle BIT FOR BIT."""
     from repro.programs import chain_dag
     problem = StencilProblem(
         [StencilStage(make_star(2, 1)), StencilStage("diffusion2d")],
         (24, 18), boundary=("clamp", "reflect"))
-    geom = BlockGeometry(2, (24, 18), problem.stencil.radius, 2, (9,),
-                         par_vec=1)
+    assert problem.exec_dag == chain_dag(problem.exec_stages)
     g = jax.random.uniform(jax.random.PRNGKey(13), (24, 18), jnp.float32,
                            0.5, 2.0)
     cf = problem.resolve_coeffs(dtype=jnp.float32)
-    dag = chain_dag(problem.exec_stages)
-    a = run_pallas_chain(problem.exec_stages, geom, g,
-                         pack_program_coeffs(problem.exec_stages, cf), 5,
-                         None, interpret=True)
-    b = run_pallas_dag(dag, geom, g, pack_dag_coeffs(dag, cf), 5, None,
-                       interpret=True)
-    assert np.array_equal(np.asarray(a), np.asarray(b)), \
-        "chain and DAG executors diverged on a linear program"
+    p = plan(problem, RunConfig(backend="pallas_interpret", par_time=2,
+                                bsize=9, par_vec=1))
+    got = p.run(g, iters=5)
+    want = oracle_dag_run(problem.exec_dag, g, cf, 5)
+    assert np.array_equal(np.asarray(got), np.asarray(want)), \
+        "the fused chain and the DAG oracle diverged on a linear program"
 
 
 # --- DAG validation: every malformed wiring fails at construction -------------

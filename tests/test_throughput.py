@@ -26,7 +26,7 @@ from repro.api import (BackendProgram, RunConfig, StencilProblem, as_program,
                        register_backend)
 from repro.core import STENCILS, default_coeffs
 from repro.core.perf_model import TPU_V5E, predict
-from repro.kernels.ref import oracle_run
+from repro.kernels.ref import oracle_dag_run, oracle_run
 
 DIMS2 = (12, 20)
 DIMS3 = (7, 19, 17)
@@ -123,8 +123,9 @@ def test_run_batch_fallback_for_unbatched_custom_backend():
 
     def factory(problem, config, geom):
         def execute(grid, coeffs, iters, aux=None):
+            # the payload is one coefficient dict per stage of the DAG
             calls.append(int(iters))
-            return oracle_run(problem.stencil, grid, coeffs, iters, aux)
+            return oracle_dag_run(problem.exec_dag, grid, coeffs, iters, aux)
         return execute
 
     register_backend("test_unbatched", factory)
